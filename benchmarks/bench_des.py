@@ -59,6 +59,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (pack_workload, resolve_ring, simulate_packet,
                         simulate_packet_reference)
 from repro.workload.lublin import WorkloadParams, generate_workload
@@ -325,6 +326,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=BENCH_PATH,
                     help="output JSON path (default: results/BENCH_des.json)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core import PAPER_INIT_PROPS, PAPER_SCALE_RATIOS
     if args.smoke:

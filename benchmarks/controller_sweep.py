@@ -60,6 +60,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.des import ChaosConfig
 from repro.service import ServiceConfig, TickFaults, run_service
 from repro.service.driver import default_controllers
@@ -281,6 +282,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scenarios", default=None,
                     help="comma-separated scenario subset (default: all)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     scenario_filter = (args.scenarios.split(",") if args.scenarios else None)
     t0 = time.perf_counter()

@@ -50,6 +50,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (PAPER_INIT_PROPS, PAPER_SCALE_RATIOS, ChaosConfig,
                         chaos_axis_len, group_workloads, run_baselines,
                         run_cohort_grid, sweep_plan)
@@ -282,6 +283,7 @@ def main():
                     help="jobs per workload (default: the paper's 5000; "
                          "smaller for smoke/CI runs)")
     args = ap.parse_args()
+    enable_compile_cache()
     dtype = (np.float64 if args.float64
              else np.float32 if args.float32 else None)
     names = args.workloads.split(",") if args.workloads else None

@@ -1,6 +1,7 @@
 """Roofline analysis from the dry-run's compiled artifacts (§Roofline).
 
-Three terms per (arch x shape x mesh) cell, in seconds per step per device:
+Three terms per (arch x shape x mesh) cell, in seconds per step per device,
+from the device's published peaks (`DEVICE_PEAKS`; TPU v5e below):
 
   compute    = FLOPs/device / 197 TFLOP/s (bf16)
   memory     = HBM bytes/device / 819 GB/s
@@ -35,9 +36,24 @@ from repro.models.analysis import (active_param_count, family_counts, pad16,
                                    param_count, param_dtype_bytes)
 from repro.models.config import ModelConfig
 
-PEAK_FLOPS = 197e12         # TPU v5e bf16 per chip
-HBM_BW = 819e9              # bytes/s per chip
-LINK_BW = 50e9              # bytes/s per ICI link
+#: Published per-chip peaks, keyed by `jax.Device.device_kind`. Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of
+#: HBM bandwidth, 1,600 Gbit/s of interconnect over four 50 GB/s links.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+#: the chip the dry-run and the event-step model are sized for
+REFERENCE_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The published peaks of `device_kind`; an unlisted device raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to DEVICE_PEAKS with their source") from None
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
 
@@ -218,8 +234,8 @@ def event_step_roofline(n_jobs: int, n_types: int, ring: int,
     """Predicted ceiling for one DES experiment on the reference device.
 
     Applies the §Roofline terms to `event_step_cost`: a lane pays
-    ``budget`` (~3N) events, each bounded below by max(bytes/HBM_BW,
-    flops/PEAK_FLOPS) with the byte traffic amortized over the `n_lanes`
+    ``budget`` (~3N) events, each bounded below by max(bytes/hbm_bw,
+    flops/peak_flops) with the byte traffic amortized over the `n_lanes`
     lanes of one dispatch (the flop term never binds — the step is
     hundreds of bytes per ~100 flops). ``predicted_ms_per_experiment``
     is what an HBM-resident scan step costs at the device's streaming
@@ -229,19 +245,21 @@ def event_step_roofline(n_jobs: int, n_types: int, ring: int,
     event-step kernel chases. BENCH_des records both next to the
     measured engines.
     """
+    peaks = device_peaks(REFERENCE_DEVICE_KIND)
     cost = event_step_cost(n_jobs, n_types, ring, dtype_bytes, chaos)
     ev = int(budget) if budget is not None else 3 * int(n_jobs)
     lanes = max(1, int(n_lanes))
-    mem_s = ev * cost["bytes_per_event"] / HBM_BW
-    flop_s = ev * cost["flops_per_event"] / PEAK_FLOPS
-    resident_s = ev * cost["gather_bytes_per_event"] / HBM_BW
+    mem_s = ev * cost["bytes_per_event"] / peaks["hbm_bw"]
+    flop_s = ev * cost["flops_per_event"] / peaks["peak_flops"]
+    resident_s = ev * cost["gather_bytes_per_event"] / peaks["hbm_bw"]
     return {
         **cost,
         "events_per_lane": ev, "n_lanes": lanes,
         "bound": "memory" if mem_s >= flop_s else "compute",
         "predicted_ms_per_experiment": max(mem_s, flop_s) * 1e3,
         "state_resident_ms_per_experiment": max(resident_s, flop_s) * 1e3,
-        "peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
+        "device_kind": REFERENCE_DEVICE_KIND,
+        "peak_flops": peaks["peak_flops"], "hbm_bw": peaks["hbm_bw"],
     }
 
 
@@ -310,9 +328,10 @@ def analyze_record(rec: dict) -> Optional[Roofline]:
     hbm = per_device_hbm(cfg, shape, strategy, kvr, epad, chips, tp, dp, mb)
     coll = rec.get("collectives", {}).get("link_bytes_per_device", 0.0)
 
-    compute_s = total / chips / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    collective_s = coll / LINK_BW
+    peaks = device_peaks(REFERENCE_DEVICE_KIND)
+    compute_s = total / chips / peaks["peak_flops"]
+    memory_s = hbm / peaks["hbm_bw"]
+    collective_s = coll / peaks["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bound = max(terms, key=terms.get)

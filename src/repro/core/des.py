@@ -167,32 +167,6 @@ RING = 512           # static fallback ring size (used when M is traced)
 CREDIT_EPS = 1e-9    # ClusterSim _requeue's "fully credited" threshold
 
 
-def _register_optimization_barrier_batcher() -> None:
-    """Make `lax.optimization_barrier` usable under vmap on jax 0.4.x.
-
-    The chaos engine barriers its per-event float accumulates so both DES
-    engines round them identically (no engine-specific FMA fusion — see
-    `_chaos_outcome`). The primitive is elementwise-identity, so the rule
-    simply passes batch dims through; newer jax registers this upstream,
-    in which case (or if the private module moves) this is a no-op.
-    """
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:     # pragma: no cover - future jax relayout
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return
-
-    def _rule(args, dims):
-        return optimization_barrier_p.bind(*args), list(dims)
-
-    batching.primitive_batchers[optimization_barrier_p] = _rule
-
-
-_register_optimization_barrier_batcher()
-
-
 def resolve_ring(m_nodes, n_jobs: int, ring: int | None = None) -> int:
     """Ring size for the running-group buffer.
 
@@ -385,14 +359,16 @@ class _ChaosOutcome(NamedTuple):
 
 
 def _chaos_outcome(chaos: ChaosConfig, u1, u2, inject, s, work, m_grp,
-                   dur0, dtype) -> _ChaosOutcome:
+                   dur0, dtype, barrier: bool = True) -> _ChaosOutcome:
     """Per-group fault outcome, mirroring ClusterSim's _schedule/_finish.
 
     All branches are `jnp.where` with the no-fault value equal to the exact
     pre-chaos expression, so a zero ChaosConfig changes no bits. `inject`
     gates every fault (the bounded-requeue cap); precedence matches
     ClusterSim: a failure before the effective end wins over a deadline
-    kill, which wins over plain completion.
+    kill, which wins over plain completion. `barrier=False` drops the
+    closing optimization barrier, which Mosaic cannot lower (the compiled
+    packet_step kernel).
     """
     m_f = m_grp.astype(dtype)
     tiny = jnp.asarray(np.finfo(np.dtype(dtype)).tiny, dtype)
@@ -425,8 +401,10 @@ def _chaos_outcome(chaos: ChaosConfig, u1, u2, inject, s, work, m_grp,
     # for fault sweeps comes from all dispatch modes sharing the scan
     # engine (see sweep._packet_one), with the barrier keeping that
     # engine's scalar and vmapped compilations rounding alike.
-    return _ChaosOutcome(*jax.lax.optimization_barrier(
-        (dur, failed, killed, ckpt_done, credit, lost)))
+    outs = (dur, failed, killed, ckpt_done, credit, lost)
+    if barrier:
+        outs = jax.lax.optimization_barrier(outs)
+    return _ChaosOutcome(*outs)
 
 
 class DesState(NamedTuple):
@@ -1178,16 +1156,16 @@ def simulate_packet_scan(pw: PackedWorkload, k, s_init, m_nodes,
         and lanes batch via `vmap`. This stays the default everywhere —
         zero behaviour change for existing callers.
       * ``"pallas"``: the same event arithmetic as a lane-minor Pallas
-        kernel (`repro.kernels.packet_step`) with the ring state resident
-        in kernel memory across the gather/scatter chain, invoked once
-        per event for a whole dispatch of lanes. Wins on accelerators
-        where XLA would bounce the [lanes, ring] state through HBM
-        between the small fused ops of the step; on CPU it runs in
-        interpret mode (discharged back into XLA), so it is a
-        correctness/parity path there, not a fast path. Schedules and
-        integer counters are bitwise-identical to ``"xla"`` in both
-        dtypes, chaos on and off (pinned by tests/test_packet_step.py);
-        float time-integrals may differ in final ulps, same as every
+        kernel (`repro.kernels.packet_step`) with the ring state in
+        kernel memory across the select/commit chain, invoked once per
+        event for a whole dispatch of lanes. On the TPU it compiles
+        through Mosaic, float32 only (float64 raises
+        `PallasUnsupportedError`); on CPU it runs in interpret mode
+        (discharged back into XLA), so it is a correctness/parity path
+        there, not a fast path. Schedules and integer counters are
+        bitwise-identical to ``"xla"`` in both dtypes, chaos on and off
+        (pinned in interpret mode by tests/test_packet_step.py); float
+        time-integrals may differ in final ulps, same as every
         cross-engine contract in this module.
 
     A single (k, s) pair routed through ``"pallas"`` runs as a 1-lane
@@ -1322,12 +1300,12 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
     kernel invocation per event: state lives as [state, T] columns with
     lanes on the minor axis, and each scan step calls the fused
     `repro.kernels.packet_step` kernel, which advances every lane one
-    event with the ring state resident in kernel memory (VMEM on TPU;
-    interpret mode discharges it back into XLA on CPU). The event
-    arithmetic is `packet_scan_step` vectorized over the lane axis —
-    all per-lane reductions are argmax/argmin/any over the state axis
-    and every float op is elementwise, so schedules and integer
-    counters are bitwise-identical to the XLA path. Extra budget
+    event with the ring state in kernel memory (VMEM on TPU; interpret
+    mode discharges it back into XLA on CPU). The event arithmetic is
+    `packet_scan_step` vectorized over the lane axis — all per-lane
+    reductions are argmax/argmin/any over the state axis and every
+    float op is elementwise, so schedules and integer counters are
+    bitwise-identical to the XLA path. Extra budget
     segments past a lane's drain point remain masked no-ops, so a
     lane's result is independent of its dispatch companions (the
     segmented early exit stops only when ALL lanes have drained).
@@ -1389,7 +1367,6 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
 
     k_col = k[None, :]
     s_col = s[None, :]
-    t_last = jnp.reshape(pw.t_last_submit, (1, 1))
 
     def lane_act(cols: _ScanState):
         act = ((cols.next_sub[0] < N) |
@@ -1401,8 +1378,7 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
 
     def step(cols: _ScanState, _):
         return _step_ops.fused_packet_step(
-            pw.tj_prefw, pw.tj_submit, pw.submit, pw.jtype,
-            k_col, s_col, p_j, tmax_j, t_last, cols,
+            pw, k_col, s_col, p_j, tmax_j, cols,
             u1=u1, u2=u2, chaos_params=chaos_params, r_cap=R)
 
     def seg_cond(carry):

@@ -8,7 +8,7 @@ would turn a precision request into a silent no-op. This module makes the
 choice explicit and scoped:
 
   * ``dtype_scope(dtype)`` — context manager that enables x64 only while a
-    float64 simulation actually runs (wraps ``jax.experimental.enable_x64``),
+    float64 simulation actually runs (wraps ``jax.enable_x64``),
     restoring the previous state on exit. Float32 sessions never flip:
     entering the scope with float32 is a no-op.
   * ``canonical_dtype(dtype)`` — validates a requested simulation dtype
@@ -78,8 +78,7 @@ def dtype_scope(dtype):
         raise ValueError(
             f"simulation dtype must be float32 or float64, got {d}")
     if d == np.dtype(np.float64) and not x64_enabled():
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             yield d
     else:
         yield d

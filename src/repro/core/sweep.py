@@ -30,10 +30,12 @@ the workload axis on top so the WHOLE study runs as a couple of programs
     lanes (copies of the last real lane, sliced off after the gather) and
     placed with a `NamedSharding` over all local devices, so the 222-lane
     paper grid shards on 2/4/8-device backends even though 222 is not a
-    power-of-two multiple. Under `run_cohort_grid` the program is [W, L]:
-    the lane axis keeps the padded sharding (PartitionSpec(None, "lane")),
-    the stacked workload axis is replicated, and one program covers
-    W x lanes experiments (666 for a 3-flow paper cohort).
+    power-of-two multiple. Each device runs the one-device program on its
+    slice of the lanes (`per_device_lanes`, a `shard_map`). Under
+    `run_cohort_grid` the program is [W, L]: the lane axis keeps the
+    padded sharding (PartitionSpec(None, "lane")), the stacked workload
+    axis is replicated, and one program covers W x lanes experiments (666
+    for a 3-flow paper cohort).
 
 The workload axis exists because `simulate_packet_scan` takes the
 `PackedWorkload` as an *operand*: `repro.core.cohort.stack_workloads`
@@ -68,6 +70,7 @@ heterogeneous flows to float64 and records the per-workload decision.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import warnings
 from functools import partial
@@ -76,6 +79,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import precision
 from repro.core.des import (STEP_IMPLS, ChaosConfig, PackedWorkload,
@@ -85,6 +89,7 @@ from repro.core.des import (STEP_IMPLS, ChaosConfig, PackedWorkload,
                             simulate_packet_scan, simulate_packet_scan_lanes)
 from repro.core.metrics import Metrics, efficiency_metrics
 from repro.core.schedulers import simulate_backfill, simulate_fcfs
+from repro.kernels.packet_step import ops as _step_ops
 from repro.workload.lublin import Workload
 
 # the paper's 37 scale-ratio values: 0.1..1 step .1, 1..10 step 1,
@@ -389,6 +394,32 @@ def lane_sharding(n_lanes: int, pad: bool = False):
     return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("lane"))
 
 
+@functools.lru_cache(maxsize=None)
+def per_device_lanes(program, sharding, m_nodes: int, ring: int,
+                     step_impl: str):
+    """``program(pw, k, s, m_nodes, ring, chaos, step_impl=...)`` as a
+    function of ``(pw, k, s, chaos)`` that runs per device.
+
+    With a lane `sharding` (`lane_sharding` / `cohort_lane_sharding`) it
+    is one jitted `shard_map`: the workload operand is replicated, `k`/`s`
+    and the outputs follow the sharding, the [L] chaos leaves follow the
+    lane axis, and each device runs `program` on its own slice of lanes.
+    Lanes are independent, so that slice is exactly the one-device
+    program at 1/n_devices of the width. Mosaic does not partition the
+    compiled pallas step by itself, so this explicit form is what lets
+    the fused layout shard it; the XLA step takes the same form. With
+    ``sharding=None`` it is `program` on one device.
+    """
+    def body(pw, k, s, chaos):
+        return program(pw, k, s, m_nodes, ring, chaos, step_impl=step_impl)
+    if sharding is None:
+        return body
+    lane = sharding.spec
+    return jax.jit(jax.shard_map(
+        body, mesh=sharding.mesh, in_specs=(P(), lane, lane, P("lane")),
+        out_specs=lane, check_vma=False))
+
+
 def resolve_mode(mode: str, n_lanes: int, n_workloads: int = 1,
                  step_impl: str = "xla") -> str:
     """Resolve mode='auto' to the concrete dispatch layout; validate others.
@@ -459,7 +490,7 @@ def sweep_plan(mode: str, n_lanes: int, n_workloads: int = 1,
         "mode": resolved,
         "step_impl": step_impl,
         "step_interpret": bool(step_impl == "pallas"
-                               and jax.default_backend() == "cpu"),
+                               and _step_ops.interpret_mode()),
         "n_lanes": n_lanes,
         "n_workloads": n_workloads,
         "total_experiments": n_lanes * n_workloads,
@@ -549,8 +580,8 @@ def _run_lanes_fused(pw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
         s_lanes = jax.device_put(s_lanes, sharding)
         if chaos is not None:
             chaos = jax.device_put(chaos, sharding)
-    out = _packet_lanes(pw, k_lanes, s_lanes, m_nodes, ring, chaos,
-                        step_impl=step_impl)
+    out = per_device_lanes(_packet_lanes, sharding, m_nodes, ring,
+                           step_impl)(pw, k_lanes, s_lanes, chaos)
     return jax.tree.map(lambda x: np.asarray(x)[:L], out)
 
 
@@ -702,8 +733,8 @@ def _run_cohort_fused(spw, k_l2, s_l2, m_nodes, ring, chaos=None,
             # chaos leaves are [L]: shard with the 1-D lane sharding that
             # matches the inner (lane) axis of the [W, L] operands
             chaos = jax.device_put(chaos, lane_sharding(L + pad, pad=True))
-    out = _packet_cohort_lanes(spw, k_l2, s_l2, m_nodes, ring, chaos,
-                               step_impl=step_impl)
+    out = per_device_lanes(_packet_cohort_lanes, sharding, m_nodes, ring,
+                           step_impl)(spw, k_l2, s_l2, chaos)
     return jax.tree.map(lambda x: np.asarray(x)[:, :L], out)
 
 

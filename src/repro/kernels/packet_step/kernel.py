@@ -4,304 +4,284 @@ One invocation advances EVERY lane of a dispatch by one event: the
 branchless select between group formation and event consumption, the
 group-ring commit (including the packed requeue span stash from the
 chaos engine), chaos outcome resolution, and the metric accumulates —
-the whole body of `repro.core.des.packet_scan_step`, vectorized over a
+the body of `repro.core.des.packet_scan_step`, vectorized over a
 trailing lane axis T. State is carried as [state, T] columns (scalars
 as [1, T], per-type rows as [H, T], ring rows as [ring, T]) so the
-gather/scatter chain of a step stays resident in kernel memory instead
-of round-tripping each small intermediate through HBM, which is what
-XLA's generic lowering does to the scan step's ~40 fused ops.
+select/commit chain of a step stays in kernel memory instead of
+round-tripping each small intermediate through HBM.
 
-Bitwise contract: every float op here is elementwise and every
-reduction is an integer/boolean/arg reduction over the state axis, so
-per-lane results are bit-identical to the scalar `packet_scan_step`
-(ref.py) in both dtypes, chaos on and off — tests/test_packet_step.py
-pins this through the interpret path, which discharges the kernel back
-into the enclosing XLA program on CPU.
+Mosaic form: every per-lane value is a [1, T] row and every access to
+the small state axes (types H, ring slots) is an iota-compare select —
+`_first` for argmax/argmin, `_pick` for a row read, masked `where` for a
+row write — which is exact and lowers on the TPU. Lookups along the job
+axis (the per-type prefix tables at head/tail, the next submission, the
+chaos draws of the forming group and the finish event's credit walk)
+are gathered by `ops.step_gathers` in the enclosing XLA program before
+the call: each of their indices is a function of the state at the start
+of the step.
 
-The event arithmetic deliberately REUSES the des.py helpers
-(`_chaos_outcome`, `_resolve_remnant`, `_pool_decode`, the packet
-policy functions): they are shape-polymorphic, so the kernel body is
-the same source of truth as the XLA engine, just indexed by lane.
+Bitwise contract: every float op here is elementwise, every reduction
+is an integer max/min/sum or a float max/min over the state axis, and
+every select returns the selected element unchanged, so per-lane results
+are bit-identical to the scalar `packet_scan_step` (ref.py) in both
+dtypes, chaos on and off — tests/test_packet_step.py pins this through
+the interpret path, which discharges the kernel back into the enclosing
+XLA program on CPU.
 """
 from __future__ import annotations
 
-from types import SimpleNamespace
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import packet
-from repro.core.des import (CREDIT_EPS, INF, ChaosConfig, _chaos_outcome,
-                            _pool_decode, _resolve_remnant, _window_overlap)
+from repro.core.des import (CREDIT_EPS, INF, ChaosConfig, _ScanState,
+                            _chaos_outcome, _window_overlap)
 
 #: number of _ScanState fields carried as [*, T] columns
-N_STATE_COLS = 23
+N_STATE_COLS = len(_ScanState._fields)
 
 
-def event_step_kernel(*refs, n_jobs: int, r_cap: int, has_chaos: bool):
+class StepGathers(NamedTuple):
+    """Job-axis lookups of one step, gathered in XLA ([H, T] or [1, T])."""
+    pf_tail: jnp.ndarray    # [H, T] tj_prefw[h, tail[h]]
+    pf_head: jnp.ndarray    # [H, T] tj_prefw[h, head[h]]
+    ts_head: jnp.ndarray    # [H, T] tj_submit[h, min(head[h], N - 1)]
+    t_sub: jnp.ndarray      # [1, T] next submit time (+inf once drained)
+    sub_j: jnp.ndarray      # [1, T] type of the next submission
+
+
+class ChaosGathers(NamedTuple):
+    """Chaos-path lookups of one step, gathered in XLA."""
+    u1: jnp.ndarray         # [1, T] straggler draw of the forming group
+    u2: jnp.ndarray         # [1, T] failure draw of the forming group
+    pf_qlo: jnp.ndarray     # [H, T] tj_prefw[h, pool head or head[h]]
+    p_cnt: jnp.ndarray      # [H, T] _pool_decode(pool_code) count
+    p_lo: jnp.ndarray       # [H, T] ... span head rank
+    p_frag: jnp.ndarray     # [H, T] ... fragmented bit (int32 0/1)
+    rem_cnt: jnp.ndarray    # [1, T] _resolve_remnant at the finishing slot
+    rem_w: jnp.ndarray
+    rem_old: jnp.ndarray
+    rem_lo: jnp.ndarray
+    rem_hi: jnp.ndarray
+    rem_walk: jnp.ndarray   # int32 0/1
+
+
+def _first(x, reduce):
+    """Row index (as [1, T]) of the first `reduce`-extreme of each column:
+    `jnp.argmax` (reduce=jnp.max) or `jnp.argmin` (jnp.min) over axis 0."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    hit = x == reduce(x, axis=0, keepdims=True)
+    return jnp.min(jnp.where(hit, rows, x.shape[0]), axis=0, keepdims=True)
+
+
+def _rows(n, idx):
+    """[n, T] mask of row `idx` ([1, T]) in each column."""
+    return jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[1]), 0) == idx
+
+
+def _pick(x, sel):
+    """The element of each column of `x` where `sel` holds (exactly one)."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        low = jnp.asarray(-jnp.inf, x.dtype)
+    else:
+        low = jnp.asarray(jnp.iinfo(x.dtype).min, x.dtype)
+    return jnp.max(jnp.where(sel, x, low), axis=0, keepdims=True)
+
+
+def _any(x):
+    return jnp.max(x.astype(jnp.int32), axis=0, keepdims=True) > 0
+
+
+def event_step_kernel(*refs, n_jobs: int, r_cap: int, has_chaos: bool,
+                      interpret: bool):
     """Pallas kernel body. Operand order (built by ops.fused_packet_step):
 
-    inputs:  tj_prefw [H, N+1], tj_submit [H, N], submit [N], jtype [N],
-             k [1, T], s [1, T], p_j [H], tmax_j [H], t_last [1, 1],
-             then iff has_chaos: u1 [L, T], u2 [L, T] and the five fault
-             parameter columns [1, T] (mtbf, ckpt, prob, factor,
-             deadline), then the 23 state columns in _ScanState order.
-    outputs: the 23 updated state columns (aliased onto the inputs),
-             then the 4 log records (key, t, m, head_w) as [1, T].
+    inputs:  k [1, T], s [1, T], p_j [H, 1], tmax_j [H, 1], t_last [1, 1],
+             the StepGathers fields, then iff has_chaos the five fault
+             parameter columns [1, T] (mtbf, ckpt, prob, factor, deadline)
+             and the ChaosGathers fields, then the state columns in
+             _ScanState order.
+    outputs: the updated state columns (aliased onto the inputs), then
+             the 4 log records (key, t, m, head_w) as [1, T].
+
+    The chaos path's optimization barriers pin XLA's fusion, so they are
+    traced only when `interpret` discharges the body into XLA; Mosaic
+    lowers no barrier.
     """
     N = n_jobs
-    (prefw_ref, tsub_ref, submit_ref, jtype_ref, k_ref, s_ref, pj_ref,
-     tmax_ref, tlast_ref) = refs[:9]
-    off = 9
+    n_in = len(refs) - N_STATE_COLS - 4
+    vals = [r[...] for r in refs[:n_in]]
+    k, s, p_j, tmax_j, t_last = vals[:5]
+    off = 5
+    g = StepGathers(*vals[off:off + len(StepGathers._fields)])
+    off += len(StepGathers._fields)
     if has_chaos:
-        (u1_ref, u2_ref, mtbf_ref, ckpt_ref, prob_ref, factor_ref,
-         dead_ref) = refs[off:off + 7]
-        off += 7
-    st = refs[off:off + N_STATE_COLS]
-    out = refs[off + N_STATE_COLS:off + 2 * N_STATE_COLS]
-    y_out = refs[off + 2 * N_STATE_COLS:off + 2 * N_STATE_COLS + 4]
+        chaos = ChaosConfig(*vals[off:off + 5])
+        off += 5
+        cg = ChaosGathers(*vals[off:off + len(ChaosGathers._fields)])
+        off += len(ChaosGathers._fields)
+    st = _ScanState(*vals[off:n_in])
+    out = refs[n_in:n_in + N_STATE_COLS]
+    y_out = refs[n_in + N_STATE_COLS:]
 
-    prefw = prefw_ref[...]
-    tsub = tsub_ref[...]
-    submit = submit_ref[...]
-    jtypes = jtype_ref[...]
-    k = k_ref[...][0]
-    s = s_ref[...][0]
-    p_j = pj_ref[...]
-    tmax_j = tmax_ref[...]
-    t_last = tlast_ref[...][0, 0]
-
-    t = st[0][...][0]
-    next_sub = st[1][...][0]
-    head = st[2][...]
-    tail = st[3][...]
-    m_free = st[4][...][0]
-    grp_end = st[5][...]
-    grp_m = st[6][...]
-    qlen_int = st[7][...][0]
-    busy_ns = st[8][...][0]
-    useful_ns = st[9][...][0]
-    n_groups = st[10][...][0]
-    pool_w = st[11][...]
-    pool_oldest = st[12][...]
-    pool_code = st[13][...]
-    grp_jtype = st[14][...]
-    grp_rem_w = st[15][...]
-    grp_rem_cnt = st[16][...]
-    grp_rem_oldest = st[17][...]
-    lost_work = st[18][...][0]
-    failures = st[19][...][0]
-    straggler_kills = st[20][...][0]
-    requeues = st[21][...][0]
-    requeued_jobs = st[22][...][0]
-
-    dtype = t.dtype
-    T = t.shape[0]
-    lanes = jnp.arange(T)
+    H = st.head.shape[0]
+    ring = st.grp_end.shape[0]
+    dtype = st.t.dtype
+    t = st.t
     key_pad = jnp.iinfo(jnp.int32).max
     zero_f = jnp.zeros((), dtype)
     zero_i = jnp.zeros((), jnp.int32)
     one_i = jnp.ones((), jnp.int32)
 
-    nonempty = tail > head                                   # [H, T]
+    nonempty = st.tail > st.head                             # [H, T]
     if has_chaos:
-        nonempty = nonempty | (pool_code > 0)
-    free_mask = jnp.isinf(grp_end)                           # [ring, T]
-    queued = jnp.any(nonempty, axis=0)                       # [T]
-    active = ((next_sub < N) | jnp.any(~jnp.isinf(grp_end), axis=0) |
-              jnp.any(tail > head, axis=0))
+        nonempty = nonempty | (st.pool_code > 0)
+    free_mask = jnp.isinf(st.grp_end)                        # [ring, T]
+    queued = _any(nonempty)                                  # [1, T]
+    active = ((st.next_sub < N) | _any(~free_mask) |
+              _any(st.tail > st.head))
     if has_chaos:
-        active = active | jnp.any(pool_code > 0, axis=0)
-    can_sched = (m_free > 0) & queued & jnp.any(free_mask, axis=0)
+        active = active | _any(st.pool_code > 0)
+    can_sched = (st.m_free > 0) & queued & _any(free_mask)
     do_sched = active & can_sched
     do_event = active & ~can_sched
 
     # greedy scheduling pass (paper Steps 1-5), masked unless do_sched
-    sum_w = (jnp.take_along_axis(prefw, tail, axis=1) -
-             jnp.take_along_axis(prefw, head, axis=1))       # [H, T]
-    oldest = jnp.take_along_axis(tsub, jnp.minimum(head, N - 1), axis=1)
+    sum_w = g.pf_tail - g.pf_head                            # [H, T]
+    oldest = g.ts_head
     if has_chaos:
-        sum_w = sum_w + pool_w
-        oldest = jnp.minimum(oldest, pool_oldest)
-    w = packet.queue_weights(sum_w, s, p_j[:, None], oldest, t,
-                             tmax_j[:, None], nonempty)
-    j = jnp.argmax(w, axis=0).astype(jnp.int32)              # [T]
-    work = sum_w[j, lanes]
-    m_grp = packet.group_nodes(work, k, s, m_free)
+        sum_w = sum_w + st.pool_w
+        oldest = jnp.minimum(oldest, st.pool_oldest)
+    w = packet.queue_weights(sum_w, s, p_j, oldest, t, tmax_j, nonempty)
+    j = _first(w, jnp.max)                                   # [1, T]
+    at_j = _rows(H, j)
+    work = _pick(sum_w, at_j)
+    m_grp = packet.group_nodes(work, k, s, st.m_free)
     dur = packet.group_duration(work, s, m_grp)
-    sslot = jnp.argmax(free_mask, axis=0)
-    head_w = prefw[j, head[j, lanes]]
+    sslot = _first(free_mask.astype(jnp.int32), jnp.max)
+    at_s = _rows(ring, sslot)
+    head_w = _pick(g.pf_head, at_j)
+    tail_j = _pick(st.tail, at_j)
+    head_j = _pick(st.head, at_j)
     if not has_chaos:
         t_gfin = t + dur
         useful_end = t_gfin
     else:
-        u1 = u1_ref[...]
-        u2 = u2_ref[...]
-        chaos = ChaosConfig(
-            mtbf_chip_hours=mtbf_ref[...][0],
-            ckpt_period=ckpt_ref[...][0],
-            straggler_prob=prob_ref[...][0],
-            straggler_factor=factor_ref[...][0],
-            straggler_deadline=dead_ref[...][0])
-        L_cap = u1.shape[0]
-        gslot = jnp.minimum(n_groups, L_cap - 1)
-        out_c = _chaos_outcome(chaos, u1[gslot, lanes], u2[gslot, lanes],
-                               requeues < r_cap, s, work, m_grp, dur,
-                               dtype)
+        out_c = _chaos_outcome(chaos, cg.u1, cg.u2, st.requeues < r_cap, s,
+                               work, m_grp, dur, dtype, barrier=interpret)
         t_gfin = t + out_c.dur
         useful_end = jnp.where(out_c.failed,
                                t + s + out_c.ckpt_done, t_gfin)
         requeued = do_sched & (out_c.failed | out_c.killed)
         eps = jnp.asarray(CREDIT_EPS, dtype)
-        p_cnt, p_lo, p_frag = _pool_decode(pool_code[j, lanes], N)
+        p_cnt = _pick(cg.p_cnt, at_j)
         has_pool = p_cnt > 0
-        qlo = jnp.where(has_pool, p_lo, head[j, lanes])
+        qlo = jnp.where(has_pool, _pick(cg.p_lo, at_j), head_j)
         res0 = jnp.where(has_pool, jnp.maximum(
-            head_w - prefw[j, qlo] - pool_w[j, lanes], zero_f), zero_f)
-        walk_ok = ~(has_pool & p_frag)
+            head_w - _pick(cg.pf_qlo, at_j) - _pick(st.pool_w, at_j),
+            zero_f), zero_f)
+        walk_ok = ~(has_pool & (_pick(cg.p_frag, at_j) > 0))
         avail = res0 + out_c.credit
-        span_code = 1 + qlo * (N + 1) + tail[j, lanes]
+        span_code = 1 + qlo * (N + 1) + tail_j
         rem_agg = work - out_c.credit
         a_has = requeued & (rem_agg > eps)
-        a_cnt = (tail[j, lanes] - head[j, lanes]) + p_cnt
+        a_cnt = (tail_j - head_j) + p_cnt
         code = jnp.where(requeued & walk_ok, span_code,
                          jnp.where(a_has, -a_cnt, zero_i))
         stash_w = jnp.where(
             requeued & walk_ok, avail,
             jnp.where(a_has, jnp.maximum(rem_agg, zero_f), zero_f))
-        stash_old = jnp.where(a_has & ~walk_ok, oldest[j, lanes], INF)
+        stash_old = jnp.where(a_has & ~walk_ok, _pick(oldest, at_j), INF)
     busy_inc = m_grp.astype(dtype) * _window_overlap(t, t_gfin, t_last)
     useful_inc = m_grp.astype(dtype) * _window_overlap(
         t + s, useful_end, t_last)
-    if has_chaos:
+    if has_chaos and interpret:
         busy_inc, useful_inc = jax.lax.optimization_barrier(
             (busy_inc, useful_inc))
 
     # event step (submission or completion), masked unless do_event
-    t_sub = jnp.where(next_sub < N,
-                      submit[jnp.minimum(next_sub, N - 1)], INF)
-    eslot = jnp.argmin(grp_end, axis=0)
-    t_efin = grp_end[eslot, lanes]
-    take_sub = t_sub <= t_efin
-    t_new = jnp.where(take_sub, t_sub, t_efin)
-    qlen = jnp.sum(tail - head, axis=0).astype(dtype)
+    eslot = _first(st.grp_end, jnp.min)
+    at_e = _rows(ring, eslot)
+    t_efin = _pick(st.grp_end, at_e)
+    take_sub = g.t_sub <= t_efin
+    t_new = jnp.where(take_sub, g.t_sub, t_efin)
+    qlen = jnp.sum(st.tail - st.head, axis=0, keepdims=True).astype(dtype)
     if has_chaos:
-        qlen = qlen + jnp.sum(pool_code % (N + 1), axis=0).astype(dtype)
+        qlen = qlen + jnp.sum(cg.p_cnt, axis=0, keepdims=True).astype(dtype)
     q_inc = qlen * _window_overlap(t, t_new, t_last)
-    if has_chaos:
+    if has_chaos and interpret:
         q_inc = jax.lax.optimization_barrier(q_inc)
-    sub_j = jtypes[jnp.minimum(next_sub, N - 1)]
 
     do_submit = do_event & take_sub
     do_finish = do_event & ~take_sub
+    set_j = at_j & do_sched
+    set_s = at_s & do_sched
+    clr_e = at_e & do_finish
 
-    new_head = head.at[j, lanes].set(
-        jnp.where(do_sched, tail[j, lanes], head[j, lanes]))
-    new_tail = tail.at[sub_j, lanes].add(
-        jnp.where(do_submit, one_i, zero_i))
-    new_m_free = (m_free - jnp.where(do_sched, m_grp, zero_i)
-                  + jnp.where(do_finish, grp_m[eslot, lanes], zero_i))
-    new_grp_end = grp_end.at[sslot, lanes].set(
-        jnp.where(do_sched, t_gfin, grp_end[sslot, lanes]))
-    new_grp_end = new_grp_end.at[eslot, lanes].set(
-        jnp.where(do_finish, INF, new_grp_end[eslot, lanes]))
-    new_grp_m = grp_m.at[sslot, lanes].set(
-        jnp.where(do_sched, m_grp, grp_m[sslot, lanes]))
-    new_grp_m = new_grp_m.at[eslot, lanes].set(
-        jnp.where(do_finish, zero_i, new_grp_m[eslot, lanes]))
+    new = dict(
+        t=jnp.where(do_event, t_new, t),
+        next_sub=st.next_sub + jnp.where(do_submit, one_i, zero_i),
+        head=jnp.where(set_j, st.tail, st.head),
+        tail=st.tail + jnp.where(_rows(H, g.sub_j) & do_submit,
+                                 one_i, zero_i),
+        m_free=(st.m_free - jnp.where(do_sched, m_grp, zero_i)
+                + jnp.where(do_finish, _pick(st.grp_m, at_e), zero_i)),
+        grp_end=jnp.where(clr_e, INF,
+                          jnp.where(set_s, t_gfin, st.grp_end)),
+        grp_m=jnp.where(clr_e, zero_i, jnp.where(set_s, m_grp, st.grp_m)),
+        qlen_int=st.qlen_int + jnp.where(do_event, q_inc, zero_f),
+        busy_ns=st.busy_ns + jnp.where(do_sched, busy_inc, zero_f),
+        useful_ns=st.useful_ns + jnp.where(do_sched, useful_inc, zero_f),
+        n_groups=st.n_groups + jnp.where(do_sched, one_i, zero_i))
 
-    y_key = jnp.where(do_sched, j * (N + 1) + tail[j, lanes], key_pad)
-    y_t = jnp.where(do_sched, t, zero_f)
-    y_m = jnp.where(do_sched, m_grp, zero_i)
-    y_hw = jnp.where(do_sched, head_w, zero_f)
-
-    if not has_chaos:
-        new_pool_w, new_pool_oldest, new_pool_code = (
-            pool_w, pool_oldest, pool_code)
-        new_grp_jtype = grp_jtype
-        new_grp_rem_w, new_grp_rem_cnt, new_grp_rem_oldest = (
-            grp_rem_w, grp_rem_cnt, grp_rem_oldest)
-        new_lost, new_fail, new_kill = lost_work, failures, straggler_kills
-        new_req, new_reqj = requeues, requeued_jobs
-    else:
-        # finish resolves the stashed requeue span into its member set
-        # (the deferred ClusterSim credit walk) and merges it back into
-        # the per-type pool — same chain as packet_scan_step, per lane
-        j_f = grp_jtype[eslot, lanes]
-        pw_ns = SimpleNamespace(n_jobs=N, tj_prefw=prefw, tj_submit=tsub)
-        cnt_r, rem_w_r, rem_old_r, rem_lo_r, rem_hi_r, walk_r = (
-            _resolve_remnant(pw_ns, j_f, grp_rem_cnt[eslot, lanes],
-                             grp_rem_w[eslot, lanes],
-                             grp_rem_oldest[eslot, lanes], dtype))
-        old_cnt, old_lo, old_frag = _pool_decode(pool_code[j_f, lanes], N)
+    if has_chaos:
+        # finish merges the resolved requeue remnant (ops.step_gathers ran
+        # the deferred credit walk) back into the per-type pool — same
+        # chain as packet_scan_step, per lane
+        j_f = _pick(st.grp_jtype, at_e)
+        at_f = _rows(H, j_f)
+        cnt_r = cg.rem_cnt
+        old_cnt = _pick(cg.p_cnt, at_f)
         inc = do_finish & (cnt_r > 0)
         was_empty = old_cnt == 0
-        contig = rem_hi_r == head[j_f, lanes]
-        frag = jnp.where(
-            inc, old_frag | ~walk_r | ~was_empty | ~contig, old_frag)
-        new_lo = jnp.where(was_empty, rem_lo_r,
-                           jnp.minimum(old_lo, rem_lo_r))
+        contig = cg.rem_hi == _pick(st.head, at_f)
+        old_frag = _pick(cg.p_frag, at_f) > 0
+        # where(inc, old_frag | x, old_frag) as logic: Mosaic selects no i1
+        frag = old_frag | (inc & ((cg.rem_walk == 0) | ~was_empty | ~contig))
+        new_lo = jnp.where(was_empty, cg.rem_lo,
+                           jnp.minimum(_pick(cg.p_lo, at_f), cg.rem_lo))
         new_code = ((new_lo * 2 + frag.astype(jnp.int32))
                     * (N + 1) + old_cnt + cnt_r)
-        new_pool_w = pool_w.at[j, lanes].set(
-            jnp.where(do_sched, zero_f, pool_w[j, lanes]))
-        new_pool_w = new_pool_w.at[j_f, lanes].add(
-            jnp.where(do_finish, rem_w_r, zero_f))
-        new_pool_oldest = pool_oldest.at[j, lanes].set(
-            jnp.where(do_sched, INF, pool_oldest[j, lanes]))
-        new_pool_oldest = new_pool_oldest.at[j_f, lanes].min(
-            jnp.where(do_finish, rem_old_r, INF))
-        new_pool_code = pool_code.at[j, lanes].set(
-            jnp.where(do_sched, zero_i, pool_code[j, lanes]))
-        new_pool_code = new_pool_code.at[j_f, lanes].set(
-            jnp.where(inc, new_code, new_pool_code[j_f, lanes]))
-        new_grp_jtype = grp_jtype.at[sslot, lanes].set(
-            jnp.where(do_sched, j, grp_jtype[sslot, lanes]))
-        new_grp_rem_w = grp_rem_w.at[sslot, lanes].set(
-            jnp.where(do_sched, stash_w, grp_rem_w[sslot, lanes]))
-        new_grp_rem_w = new_grp_rem_w.at[eslot, lanes].set(
-            jnp.where(do_finish, zero_f, new_grp_rem_w[eslot, lanes]))
-        new_grp_rem_cnt = grp_rem_cnt.at[sslot, lanes].set(
-            jnp.where(do_sched, code, grp_rem_cnt[sslot, lanes]))
-        new_grp_rem_cnt = new_grp_rem_cnt.at[eslot, lanes].set(
-            jnp.where(do_finish, zero_i, new_grp_rem_cnt[eslot, lanes]))
-        new_grp_rem_oldest = grp_rem_oldest.at[sslot, lanes].set(
-            jnp.where(do_sched, stash_old, grp_rem_oldest[sslot, lanes]))
-        new_grp_rem_oldest = new_grp_rem_oldest.at[eslot, lanes].set(
-            jnp.where(do_finish, INF, new_grp_rem_oldest[eslot, lanes]))
-        new_lost = lost_work + jnp.where(do_sched, out_c.lost, zero_f)
-        new_fail = failures + jnp.where(do_sched & out_c.failed,
-                                        one_i, zero_i)
-        new_kill = straggler_kills + jnp.where(
-            do_sched & out_c.killed & ~out_c.failed, one_i, zero_i)
-        new_req = requeues + jnp.where(requeued, one_i, zero_i)
-        new_reqj = requeued_jobs + jnp.where(do_finish, cnt_r, zero_i)
+        pool_w = jnp.where(set_j, zero_f, st.pool_w)
+        pool_oldest = jnp.where(set_j, INF, st.pool_oldest)
+        pool_code = jnp.where(set_j, zero_i, st.pool_code)
+        new.update(
+            pool_w=jnp.where(at_f, pool_w + jnp.where(
+                do_finish, cg.rem_w, zero_f), pool_w),
+            pool_oldest=jnp.where(at_f, jnp.minimum(pool_oldest, jnp.where(
+                do_finish, cg.rem_old, INF)), pool_oldest),
+            pool_code=jnp.where(at_f & inc, new_code, pool_code),
+            grp_jtype=jnp.where(set_s, j, st.grp_jtype),
+            grp_rem_w=jnp.where(clr_e, zero_f,
+                                jnp.where(set_s, stash_w, st.grp_rem_w)),
+            grp_rem_cnt=jnp.where(clr_e, zero_i,
+                                  jnp.where(set_s, code, st.grp_rem_cnt)),
+            grp_rem_oldest=jnp.where(clr_e, INF, jnp.where(
+                set_s, stash_old, st.grp_rem_oldest)),
+            lost_work=st.lost_work + jnp.where(do_sched, out_c.lost, zero_f),
+            failures=st.failures + jnp.where(do_sched & out_c.failed,
+                                             one_i, zero_i),
+            straggler_kills=st.straggler_kills + jnp.where(
+                do_sched & out_c.killed & ~out_c.failed, one_i, zero_i),
+            requeues=st.requeues + jnp.where(requeued, one_i, zero_i),
+            requeued_jobs=st.requeued_jobs + jnp.where(
+                do_finish, cnt_r, zero_i))
 
-    out[0][...] = jnp.where(do_event, t_new, t)[None, :]
-    out[1][...] = (next_sub + jnp.where(do_submit, one_i, zero_i))[None, :]
-    out[2][...] = new_head
-    out[3][...] = new_tail
-    out[4][...] = new_m_free[None, :]
-    out[5][...] = new_grp_end
-    out[6][...] = new_grp_m
-    out[7][...] = (qlen_int + jnp.where(do_event, q_inc, zero_f))[None, :]
-    out[8][...] = (busy_ns + jnp.where(do_sched, busy_inc, zero_f))[None, :]
-    out[9][...] = (useful_ns +
-                   jnp.where(do_sched, useful_inc, zero_f))[None, :]
-    out[10][...] = (n_groups + jnp.where(do_sched, one_i, zero_i))[None, :]
-    out[11][...] = new_pool_w
-    out[12][...] = new_pool_oldest
-    out[13][...] = new_pool_code
-    out[14][...] = new_grp_jtype
-    out[15][...] = new_grp_rem_w
-    out[16][...] = new_grp_rem_cnt
-    out[17][...] = new_grp_rem_oldest
-    out[18][...] = new_lost[None, :]
-    out[19][...] = new_fail[None, :]
-    out[20][...] = new_kill[None, :]
-    out[21][...] = new_req[None, :]
-    out[22][...] = new_reqj[None, :]
-    y_out[0][...] = y_key.astype(jnp.int32)[None, :]
-    y_out[1][...] = y_t[None, :]
-    y_out[2][...] = y_m.astype(jnp.int32)[None, :]
-    y_out[3][...] = y_hw[None, :]
+    for ref, name in zip(out, _ScanState._fields):
+        ref[...] = new.get(name, getattr(st, name))
+    y_out[0][...] = jnp.where(do_sched, j * (N + 1) + tail_j, key_pad)
+    y_out[1][...] = jnp.where(do_sched, t, zero_f)
+    y_out[2][...] = jnp.where(do_sched, m_grp, zero_i)
+    y_out[3][...] = jnp.where(do_sched, head_w, zero_f)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 DEFAULT_CHUNK = 128
 DEFAULT_BD = 256
@@ -103,7 +102,7 @@ def lru_chunked(log_a, b, h0=None, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((B, Dp), b.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

@@ -17,6 +17,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.des import ChaosConfig
 from repro.service import ServiceConfig, run_service
 from repro.service.driver import default_controllers
@@ -45,6 +46,7 @@ def main(argv=None):
                     help="wait-seconds per machine-second of expected lost "
                          "work (with --chaos; default 0.1)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     flows = drift_scenarios(n_jobs=args.jobs, nodes=args.nodes,
                             n_segments=args.segments)

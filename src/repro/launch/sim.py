@@ -11,6 +11,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (PAPER_SCALE_RATIOS, plateau_threshold,
                         run_baselines, run_packet_grid)
 from repro.workload.lublin import (WorkloadParams, generate_workload,
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--baselines", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     homog = args.workload.startswith("homog")
     load = float(args.workload[-4:])

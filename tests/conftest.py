@@ -1,7 +1,8 @@
 import os
 
-# Keep tests on the single real CPU device; the 512-device placeholder
-# environment is reserved for the dry-run (launched as a subprocess).
+# Tests run on the CPU backend (Pallas kernels in interpret mode); the chip
+# is exercised by chip_smoke.py, and tests/test_tpu_compile.py compiles for
+# a described TPU without one.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np

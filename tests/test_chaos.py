@@ -406,7 +406,7 @@ class TestClusterSimDifferential:
         member count (2) because it never knew where the credit landed.
 
         Hand model (s=100, M=4, k=0.25, mtbf=1 chip-hour, ckpt=300;
-        seed 6 picked so groups 1 and 3 survive while group 2 fails at
+        seed 118 picked so groups 1 and 3 survive while group 2 fails at
         t_fail in [1600, 1900) => ckpt_done 1500, credit 4*1500=6000):
           A: submit 0, work 6000 -> group 1 [0, 1600), all 4 chips
           B, C: submit 1, 2 -> queue; group 2 at t=1600, work 10000,
@@ -414,7 +414,7 @@ class TestClusterSimDifferential:
           group 3 at t=4200: {C}, work 4000, dur 1100 -> makespan 5300.
         """
         chaos = ChaosConfig(mtbf_chip_hours=1.0, ckpt_period=300.0,
-                            seed=6, lane=0)
+                            seed=118, lane=0)
         submit = [0.0, 1.0, 2.0]
         runtime = [6000.0, 4000.0, 6000.0]
         rw, rs, u = _hand_des(chaos, submit, runtime, k=0.25)

@@ -158,8 +158,8 @@ class TestPlateauThreshold:
 _SHARD_SCRIPT = r"""
 import json
 import numpy as np
-from repro.core import group_workloads, lane_padding, run_cohort_grid, \
-    run_packet_grid
+from repro.core import ChaosConfig, group_workloads, lane_padding, \
+    run_cohort_grid, run_packet_grid
 from repro.core.sweep import cohort_lane_sharding, lane_sharding
 from repro.workload.lublin import WorkloadParams, generate_workload
 
@@ -190,6 +190,23 @@ cohort_match = all(
                                        mode="fused"), f)))
     for name, w in flows.items() for f in grids[name]._fields)
 
+# the pallas step (interpret mode here) in the same per-device layout,
+# fault-free and under a seeded fault grid, bitwise == the XLA step
+chaos = ChaosConfig(mtbf_chip_hours=2.0, ckpt_period=120.0,
+                    straggler_prob=0.3, straggler_factor=2.0,
+                    straggler_deadline=1.5, lane=0, seed=11)
+same = lambda a, b: all(np.array_equal(np.asarray(getattr(a, f)),
+                                       np.asarray(getattr(b, f)),
+                                       equal_nan=True) for f in a._fields)
+pallas_match = same(fused, run_packet_grid(
+    wl, ks=ks, s_props=s_props, mode="fused", step_impl="pallas"))
+chaos_fused = run_packet_grid(wl, ks=ks, s_props=s_props, mode="fused",
+                              chaos=chaos)
+chaos_match = same(chaos_fused, run_packet_grid(
+    wl, ks=ks, s_props=s_props, mode="chunked", chaos=chaos)) and same(
+    chaos_fused, run_packet_grid(wl, ks=ks, s_props=s_props, mode="fused",
+                                 chaos=chaos, step_impl="pallas"))
+
 print(json.dumps({
     "seq_avg_wait": np.asarray(seq.avg_wait).tolist(),
     "fused_avg_wait": np.asarray(fused.avg_wait).tolist(),
@@ -199,6 +216,9 @@ print(json.dumps({
     "shape": list(np.asarray(fused.avg_wait).shape),
     "cohort_match": bool(cohort_match),
     "cohort_ok": bool(all(np.asarray(g.ok).all() for g in grids.values())),
+    "pallas_match": bool(pallas_match),
+    "chaos_match": bool(chaos_match),
+    "chaos_requeues": int(np.asarray(chaos_fused.requeues).sum()),
 }))
 """
 
@@ -226,3 +246,6 @@ def test_padded_sharding_multi_device_subprocess():
     assert out["fused_n_groups"] == out["seq_n_groups"]
     assert out["cohort_ok"]
     assert out["cohort_match"]    # [W, lanes] sharded == solo fused, bitwise
+    assert out["pallas_match"]    # per-device pallas step == XLA, bitwise
+    assert out["chaos_match"]     # sharded fault grid == chunked, bitwise
+    assert out["chaos_requeues"] > 0      # the fault path genuinely ran
