@@ -400,9 +400,11 @@ def phase_four_chips():
         grids = core.run_cohort_grid(cohort, mode="fused", step_impl=impl)
         # the programs run_cohort_grid runs: per device over four chips,
         # and on one chip the same program over a quarter of the lanes
+        # (their metrics; the second output is the lanes' segment counts)
         def prog(sh, impl=impl):
-            return sweep.per_device_lanes(sweep._packet_cohort_lanes, sh,
-                                          cohort.m_nodes, cohort.ring, impl)
+            run = sweep.per_device_lanes(sweep._packet_cohort_lanes, sh,
+                                         cohort.m_nodes, cohort.ring, impl)
+            return lambda *args: run(*args)[0]
         four = prog(sharding)(spw, jax.device_put(k_l2, sharding),
                               jax.device_put(s_l2, sharding), None)
         shards = four.avg_wait.addressable_shards

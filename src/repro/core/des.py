@@ -1111,7 +1111,8 @@ def simulate_packet_scan(pw: PackedWorkload, k, s_init, m_nodes,
                          budget: int | None = None,
                          seg: int | None = None,
                          chaos: ChaosConfig | None = None,
-                         step_impl: str = "xla") -> DesResult:
+                         step_impl: str = "xla",
+                         with_segments: bool = False):
     """Packet DES as a fixed-budget `lax.scan` — the batched-lane engine.
 
     Same policy and same per-step arithmetic as `simulate_packet`, but
@@ -1171,14 +1172,21 @@ def simulate_packet_scan(pw: PackedWorkload, k, s_init, m_nodes,
     A single (k, s) pair routed through ``"pallas"`` runs as a 1-lane
     dispatch of `simulate_packet_scan_lanes`; batch callers should use
     the lanes entry point directly.
+
+    ``with_segments=True`` returns ``(DesResult, segments)``: the int32
+    number of `seg`-long segments the lane ran before it drained (the
+    while loop's own counter), so it ran ``segments * seg`` steps, masked
+    or not. Under `vmap` the count is per lane, and the batched loop ran
+    the largest of them for every lane.
     """
     _check_step_impl(step_impl)
     if step_impl == "pallas":
-        res = simulate_packet_scan_lanes(
+        res, segs = simulate_packet_scan_lanes(
             pw, jnp.asarray(k)[None], jnp.asarray(s_init)[None], m_nodes,
             priority=priority, t_max=t_max, ring=ring, budget=budget,
-            seg=seg, chaos=chaos, step_impl="pallas")
-        return jax.tree.map(lambda x: x[0], res)
+            seg=seg, chaos=chaos, step_impl="pallas", with_segments=True)
+        res = jax.tree.map(lambda x: x[0], res)
+        return (res, segs[0]) if with_segments else res
     H, N = pw.n_types, pw.n_jobs
     ring = resolve_ring(m_nodes, N, ring)
     R = resolve_max_requeues(chaos, N)
@@ -1245,7 +1253,7 @@ def simulate_packet_scan(pw: PackedWorkload, k, s_init, m_nodes,
              jnp.zeros((budget,), jnp.int32),
              jnp.zeros((budget,), dtype))
 
-    st, logs, _ = jax.lax.while_loop(
+    st, logs, n_run = jax.lax.while_loop(
         seg_cond, seg_body, (st0, logs0, jnp.zeros((), jnp.int32)))
     log_key, log_t, log_m, log_headw = logs
     start_t, run_start_t = _reconstruct_job_times(
@@ -1255,13 +1263,14 @@ def simulate_packet_scan(pw: PackedWorkload, k, s_init, m_nodes,
     if chaos is not None:
         drained = drained & jnp.all(st.pool_code == 0)
     ok = drained & jnp.all(jnp.isfinite(start_t))
-    return DesResult(start_t=start_t, run_start_t=run_start_t,
-                     qlen_int=st.qlen_int, busy_ns=st.busy_ns,
-                     useful_ns=st.useful_ns, n_groups=st.n_groups,
-                     makespan=st.t, ok=ok, budget_exhausted=~drained,
-                     lost_work=st.lost_work, failures=st.failures,
-                     straggler_kills=st.straggler_kills,
-                     requeues=st.requeues, requeued_jobs=st.requeued_jobs)
+    res = DesResult(start_t=start_t, run_start_t=run_start_t,
+                    qlen_int=st.qlen_int, busy_ns=st.busy_ns,
+                    useful_ns=st.useful_ns, n_groups=st.n_groups,
+                    makespan=st.t, ok=ok, budget_exhausted=~drained,
+                    lost_work=st.lost_work, failures=st.failures,
+                    straggler_kills=st.straggler_kills,
+                    requeues=st.requeues, requeued_jobs=st.requeued_jobs)
+    return (res, n_run) if with_segments else res
 
 
 def _lane_cols_to_rows(cols: _ScanState) -> _ScanState:
@@ -1287,7 +1296,8 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
                                budget: int | None = None,
                                seg: int | None = None,
                                chaos: ChaosConfig | None = None,
-                               step_impl: str = "xla") -> DesResult:
+                               step_impl: str = "xla",
+                               with_segments: bool = False):
     """A whole dispatch of (k, s) lanes through one scan engine.
 
     `k` and `s_init` are [T] lane arrays; `chaos` (optional) carries
@@ -1312,6 +1322,11 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
 
     Call under `jax.jit` — the pallas path issues one kernel call per
     scan step and is built to be traced, not run op-by-op.
+
+    ``with_segments=True`` returns ``(DesResult, segments)`` with [T]
+    int32 segment counts, as `simulate_packet_scan` gives them per lane;
+    the pallas path runs one loop for the whole dispatch, so every lane
+    reads that loop's count.
     """
     _check_step_impl(step_impl)
     k = jnp.atleast_1d(k)
@@ -1321,7 +1336,7 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
         run = partial(simulate_packet_scan, pw,
                       m_nodes=m_nodes, priority=priority,
                       t_max=t_max, ring=ring, budget=budget,
-                      seg=seg)
+                      seg=seg, with_segments=with_segments)
         if chaos is None:
             return jax.vmap(lambda kk, ss: run(k=kk, s_init=ss))(k, s_init)
         chaos_b = jax.tree.map(
@@ -1424,7 +1439,7 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
              jnp.zeros((budget, T), jnp.int32),
              jnp.zeros((budget, T), dtype))
 
-    cols, logs, _ = jax.lax.while_loop(
+    cols, logs, n_run = jax.lax.while_loop(
         seg_cond, seg_body, (cols0, logs0, jnp.zeros((), jnp.int32)))
     logs_lane = tuple(jnp.swapaxes(buf, 0, 1) for buf in logs)
     st_lane = _lane_cols_to_rows(cols)
@@ -1446,7 +1461,8 @@ def simulate_packet_scan_lanes(pw: PackedWorkload, k, s_init, m_nodes,
                          requeues=st.requeues,
                          requeued_jobs=st.requeued_jobs)
 
-    return jax.vmap(assemble)(logs_lane, st_lane, s)
+    res = jax.vmap(assemble)(logs_lane, st_lane, s)
+    return (res, jnp.full((T,), n_run)) if with_segments else res
 
 
 # --------------------------------------------------------------------------

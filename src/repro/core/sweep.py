@@ -81,9 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import precision
-from repro.core.des import (STEP_IMPLS, ChaosConfig, PackedWorkload,
-                            _check_step_impl, chaos_is_inert, event_budget,
+from repro.core.des import (SCAN_SEG, STEP_IMPLS, ChaosConfig,
+                            PackedWorkload, _check_step_impl,
+                            chaos_is_inert, event_budget,
                             pack_workload, resolve_max_requeues,
                             resolve_ring, simulate_packet,
                             simulate_packet_scan, simulate_packet_scan_lanes)
@@ -126,6 +128,14 @@ def _one_experiment_scan(pw, k, s, m_nodes, ring, chaos=None):
     return efficiency_metrics(pw.submit, res, m_nodes, pw.t_last_submit)
 
 
+def _lane_experiment(pw, k, s, m_nodes, ring, chaos=None):
+    """`_one_experiment_scan` and the lane's scan segment count."""
+    res, segs = simulate_packet_scan(pw, k, s, m_nodes, ring=ring,
+                                     chaos=chaos, with_segments=True)
+    return (efficiency_metrics(pw.submit, res, m_nodes, pw.t_last_submit),
+            segs)
+
+
 @partial(jax.jit, static_argnames=("m_nodes", "ring", "step_impl"))
 def _packet_one(pw, k, s, m_nodes, ring, chaos=None, step_impl="xla"):
     """Single experiment (the per-dispatch path of mode='seq').
@@ -160,6 +170,9 @@ def _packet_lanes(pw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
                   step_impl="xla"):
     """Batched lanes through the event-budget scan engine (chunked/fused).
 
+    Returns ``(Metrics, segments)``: [L] metrics and each lane's scan
+    segment count (`simulate_packet_scan(..., with_segments=True)`).
+
     `chaos` is either None (the pre-chaos trace) or a ChaosConfig whose
     leaves are [L]-aligned with the lane axis (ChaosConfig's static aux —
     seed, max_requeues — keys the jit cache via the treedef).
@@ -169,17 +182,17 @@ def _packet_lanes(pw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
     vmapped XLA step — one kernel invocation advances the whole dispatch
     one event, with bitwise-identical schedules and counters."""
     if step_impl == "pallas":
-        res = simulate_packet_scan_lanes(pw, k_lanes, s_lanes, m_nodes,
-                                         ring=ring, chaos=chaos,
-                                         step_impl="pallas")
+        res, segs = simulate_packet_scan_lanes(
+            pw, k_lanes, s_lanes, m_nodes, ring=ring, chaos=chaos,
+            step_impl="pallas", with_segments=True)
         return jax.vmap(
             lambda r: efficiency_metrics(pw.submit, r, m_nodes,
-                                         pw.t_last_submit))(res)
+                                         pw.t_last_submit))(res), segs
     if chaos is None:
-        return jax.vmap(_one_experiment_scan,
+        return jax.vmap(_lane_experiment,
                         in_axes=(None, 0, 0, None, None))(
             pw, k_lanes, s_lanes, m_nodes, ring)
-    return jax.vmap(_one_experiment_scan,
+    return jax.vmap(_lane_experiment,
                     in_axes=(None, 0, 0, None, None, 0))(
         pw, k_lanes, s_lanes, m_nodes, ring, chaos)
 
@@ -522,6 +535,23 @@ def sweep_plan(mode: str, n_lanes: int, n_workloads: int = 1,
     return plan
 
 
+def _tally(sp, n_jobs: int, n_groups, loops) -> None:
+    """Count a dispatch on its span, from outputs already on the host.
+
+    ``lane_events``: N + 2 * n_groups summed over its real lanes
+    (`n_groups`), the steps that move a lane's schedule on.
+    ``lane_steps_run``: the steps it executed, masked or not: for each
+    while loop it ran (`loops`, the segment counts of that loop's lanes,
+    padded lanes included) its lanes x its longest lane's segments x
+    `SCAN_SEG`. One loop per dispatch and chip; one per member as well
+    where the pallas step runs a cohort."""
+    n_groups = np.asarray(n_groups)
+    sp.count("lane_events",
+             n_jobs * int(n_groups.size) + 2 * int(n_groups.sum()))
+    sp.count("lane_steps_run", SCAN_SEG * sum(
+        int(np.size(seg)) * int(np.max(seg)) for seg in loops))
+
+
 def _run_lane_chunks(pw, k_lanes, s_lanes, m_nodes, ring, chunk: int,
                      chaos=None, step_impl="xla"):
     """Sorted equal-width chunks through the scan engine, then unsort.
@@ -543,17 +573,25 @@ def _run_lane_chunks(pw, k_lanes, s_lanes, m_nodes, ring, chunk: int,
     width = -(-L // n_chunks)
     order = lane_order(np.asarray(k_lanes), np.asarray(s_lanes))
     chunks = []
-    for c in range(0, L, width):
+    for i, c in enumerate(range(0, L, width)):
         idx = order[c:c + width]
         pad = width - len(idx)
         if pad:
             idx = np.concatenate([idx, np.repeat(idx[-1:], pad)])
         chaos_c = (None if chaos is None
                    else jax.tree.map(lambda x: jnp.asarray(x)[idx], chaos))
-        out = _packet_lanes(pw, k_lanes[idx], s_lanes[idx], m_nodes, ring,
-                            chaos_c, step_impl=step_impl)
-        chunks.append(jax.tree.map(lambda x: np.asarray(x)[:width - pad]
-                                   if pad else np.asarray(x), out))
+        with obs.span("repro.sweep.dispatch", chunk=i, lanes=width - pad,
+                      width=width) as sp:
+            out, segs = _packet_lanes(pw, k_lanes[idx], s_lanes[idx],
+                                      m_nodes, ring, chaos_c,
+                                      step_impl=step_impl)
+            # copied with the outputs, not in a host round trip of its own
+            segs.copy_to_host_async()
+            obs.stamp_when_ready("repro.sweep.device", segs)
+        out = jax.tree.map(np.asarray, out)
+        _tally(sp, pw.n_jobs, out.n_groups[:width - pad], [np.asarray(segs)])
+        chunks.append(jax.tree.map(lambda x: x[:width - pad] if pad else x,
+                                   out))
     gathered = jax.tree.map(lambda *x: np.concatenate(x, axis=0), *chunks)
     inv = np.empty_like(order)
     inv[order] = np.arange(L)
@@ -580,9 +618,16 @@ def _run_lanes_fused(pw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
         s_lanes = jax.device_put(s_lanes, sharding)
         if chaos is not None:
             chaos = jax.device_put(chaos, sharding)
-    out = per_device_lanes(_packet_lanes, sharding, m_nodes, ring,
-                           step_impl)(pw, k_lanes, s_lanes, chaos)
-    return jax.tree.map(lambda x: np.asarray(x)[:L], out)
+    with obs.span("repro.sweep.dispatch", chunk=0, lanes=L,
+                  width=L + pad) as sp:
+        out, segs = per_device_lanes(_packet_lanes, sharding, m_nodes, ring,
+                                     step_impl)(pw, k_lanes, s_lanes, chaos)
+        segs.copy_to_host_async()
+        obs.stamp_when_ready("repro.sweep.device", segs)
+    out, segs = jax.tree.map(np.asarray, (out, segs))
+    chips = 1 if sharding is None else len(sharding.device_set)
+    _tally(sp, pw.n_jobs, out.n_groups[:L], np.split(segs, chips))
+    return jax.tree.map(lambda x: x[:L], out)
 
 
 # --------------------------------------------------------------------------
@@ -593,6 +638,8 @@ def _run_lanes_fused(pw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
 def _packet_cohort_lanes(spw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
                          step_impl="xla"):
     """[W]-stacked workloads x [W, L] lanes: one program, W * L experiments.
+
+    Returns ``(Metrics, segments)``, both [W, L] (as `_packet_lanes`).
 
     The outer vmap batches the PackedWorkload operand itself
     (in_axes=(0, 0, 0, None, None)); the inner vmap is the existing lane
@@ -613,19 +660,19 @@ def _packet_cohort_lanes(spw, k_lanes, s_lanes, m_nodes, ring, chaos=None,
         rows = []
         for w in range(int(k_lanes.shape[0])):
             pw_w = jax.tree.map(lambda x, w=w: x[w], spw)
-            res = simulate_packet_scan_lanes(
+            res, segs = simulate_packet_scan_lanes(
                 pw_w, k_lanes[w], s_lanes[w], m_nodes, ring=ring,
-                chaos=chaos, step_impl="pallas")
-            rows.append(jax.vmap(
+                chaos=chaos, step_impl="pallas", with_segments=True)
+            rows.append((jax.vmap(
                 lambda r, p=pw_w: efficiency_metrics(
-                    p.submit, r, m_nodes, p.t_last_submit))(res))
+                    p.submit, r, m_nodes, p.t_last_submit))(res), segs))
         return jax.tree.map(lambda *x: jnp.stack(x), *rows)
     if chaos is None:
-        lanes = jax.vmap(_one_experiment_scan,
+        lanes = jax.vmap(_lane_experiment,
                          in_axes=(None, 0, 0, None, None))
         return jax.vmap(lanes, in_axes=(0, 0, 0, None, None))(
             spw, k_lanes, s_lanes, m_nodes, ring)
-    lanes = jax.vmap(_one_experiment_scan,
+    lanes = jax.vmap(_lane_experiment,
                      in_axes=(None, 0, 0, None, None, 0))
     return jax.vmap(lanes, in_axes=(0, 0, 0, None, None, None))(
         spw, k_lanes, s_lanes, m_nodes, ring, chaos)
@@ -683,6 +730,11 @@ def _run_cohort_chunks(spw, k_l2, s_l2, m_nodes, ring, chunk: int,
     shared: the k grid is identical across members and init times differ
     only by a positive per-workload scalar (s_w = S/(1-S) * mean(e_w)), so
     the k * s event-count proxy sorts every row identically.
+
+    Returns ``(Metrics, segments, dispatches)``: [W, L] device arrays in
+    grid order, and per dispatch its span with the host indices of its
+    real lanes and of its loop's lanes in those arrays (for `_tally`,
+    once the caller has them on the host).
     """
     W, L = int(k_l2.shape[0]), int(k_l2.shape[1])
     n_chunks = max(1, -(-L // max(1, chunk)))
@@ -695,25 +747,34 @@ def _run_cohort_chunks(spw, k_l2, s_l2, m_nodes, ring, chunk: int,
         if pad:
             idx = np.concatenate([idx, np.repeat(idx[-1:], pad)])
         slices.append((idx, pad))
-    rows = []
+    rows, dispatches = [], []
     for w in range(W):
         pw_w = jax.tree.map(lambda x: x[w], spw)
-        chunks = [jax.tree.map(
-            lambda x: x[:width - pad] if pad else x,
-            _packet_lanes(pw_w, k_l2[w, idx], s_l2[w, idx], m_nodes, ring,
-                          None if chaos is None else jax.tree.map(
-                              lambda x: jnp.asarray(x)[idx], chaos),
-                          step_impl=step_impl))
-            for idx, pad in slices]
+        chunks = []
+        for c, (idx, pad) in enumerate(slices):
+            with obs.span("repro.sweep.dispatch", workload=w, chunk=c,
+                          lanes=width - pad, width=width) as sp:
+                out = _packet_lanes(
+                    pw_w, k_l2[w, idx], s_l2[w, idx], m_nodes, ring,
+                    None if chaos is None else jax.tree.map(
+                        lambda x: jnp.asarray(x)[idx], chaos),
+                    step_impl=step_impl)
+                obs.stamp_when_ready("repro.sweep.device", out[1])
+            dispatches.append((sp, (w, idx[:width - pad]), [(w, idx)]))
+            chunks.append(jax.tree.map(
+                lambda x: x[:width - pad] if pad else x, out))
         rows.append(jax.tree.map(lambda *x: jnp.concatenate(x), *chunks))
     gathered = jax.tree.map(lambda *x: jnp.stack(x), *rows)
     inv = jnp.asarray(np.argsort(order, kind="stable"))
-    return jax.tree.map(lambda x: x[:, inv], gathered)
+    lanes, segs = jax.tree.map(lambda x: x[:, inv], gathered)
+    segs.copy_to_host_async()
+    return lanes, segs, dispatches
 
 
-def _run_cohort_fused(spw, k_l2, s_l2, m_nodes, ring, chaos=None,
-                      step_impl="xla"):
-    """All W x L lanes in one dispatch; lane axis padded + sharded."""
+def _place_cohort_lanes(k_l2, s_l2, chaos=None):
+    """The [W, L] lane operands padded to a device multiple with sentinel
+    lanes and sharded over the devices: ``(k, s, chaos, sharding)``, the
+    sharding None on one device."""
     L = int(k_l2.shape[1])
     pad = lane_padding(L)
     if pad:
@@ -733,9 +794,32 @@ def _run_cohort_fused(spw, k_l2, s_l2, m_nodes, ring, chaos=None,
             # chaos leaves are [L]: shard with the 1-D lane sharding that
             # matches the inner (lane) axis of the [W, L] operands
             chaos = jax.device_put(chaos, lane_sharding(L + pad, pad=True))
-    out = per_device_lanes(_packet_cohort_lanes, sharding, m_nodes, ring,
-                           step_impl)(spw, k_l2, s_l2, chaos)
-    return jax.tree.map(lambda x: np.asarray(x)[:, :L], out)
+    return k_l2, s_l2, chaos, sharding
+
+
+def _run_cohort_fused(spw, k_l2, s_l2, m_nodes, ring, n_real: int,
+                      sharding=None, chaos=None, step_impl="xla"):
+    """All W x L lanes, placed by `_place_cohort_lanes`, in one dispatch.
+
+    Returns ``(Metrics, segments, dispatches)`` as `_run_cohort_chunks`
+    does, the arrays over the padded lane axis; `n_real` lanes are real.
+    """
+    W, n_lanes = int(k_l2.shape[0]), int(k_l2.shape[1])
+    with obs.span("repro.sweep.dispatch", chunk=0, lanes=W * n_real,
+                  width=W * n_lanes) as sp:
+        lanes, segs = per_device_lanes(
+            _packet_cohort_lanes, sharding, m_nodes, ring, step_impl)(
+                spw, k_l2, s_l2, chaos)
+        segs.copy_to_host_async()
+        obs.stamp_when_ready("repro.sweep.device", segs)
+    chips = 1 if sharding is None else len(sharding.device_set)
+    q = n_lanes // chips
+    per_chip = [slice(c * q, (c + 1) * q) for c in range(chips)]
+    # the pallas step runs one loop per member, the XLA step one for all
+    loops = ([(w, b) for b in per_chip for w in range(W)]
+             if step_impl == "pallas" else [(slice(None), b)
+                                            for b in per_chip])
+    return lanes, segs, [(sp, (slice(None), slice(0, n_real)), loops)]
 
 
 def run_cohort_grid(cohort, ks: Sequence[float] = PAPER_SCALE_RATIOS,
@@ -779,15 +863,27 @@ def run_cohort_grid(cohort, ks: Sequence[float] = PAPER_SCALE_RATIOS,
         raise ValueError(
             f"mode {resolved!r} has no cohort layout; use run_packet_grid "
             f"per workload for the legacy column/row batchings")
-    if resolved == "seq":
-        return {name: run_packet_grid(wl, ks, s_props, dtype=cohort.dtype,
-                                      mode="seq", chaos=chaos,
-                                      on_budget_exhausted=on_budget_exhausted,
-                                      step_impl=step_impl)
-                for name, wl in zip(cohort.names, cohort.workloads)}
+    chips = jax.device_count() if resolved == "fused" else 1
+    with obs.span("repro.study", workloads=W, ks=K, s_props=S,
+                  layout=resolved, chips=chips):
+        if resolved == "seq":
+            return {name: run_packet_grid(
+                        wl, ks, s_props, dtype=cohort.dtype, mode="seq",
+                        chaos=chaos, on_budget_exhausted=on_budget_exhausted,
+                        step_impl=step_impl)
+                    for name, wl in zip(cohort.names, cohort.workloads)}
+        with precision.dtype_scope(cohort.dtype):
+            return _run_cohort(cohort, ks, s_props, resolved, chunk_lanes,
+                               chaos, on_budget_exhausted, step_impl)
 
+
+def _run_cohort(cohort, ks, s_props, resolved: str, chunk_lanes, chaos,
+                on_budget_exhausted: str, step_impl: str) -> dict:
+    """`run_cohort_grid`'s chunked and fused layouts, inside its span and
+    dtype scope: lane operands, the dispatches, the gather."""
+    K, S, W = len(ks), len(s_props), cohort.n_workloads
     dtype = cohort.dtype
-    with precision.dtype_scope(dtype):
+    with obs.span("repro.study.prepare"):
         spw = cohort.pack()
         m_nodes, ring = cohort.m_nodes, cohort.ring
         ks_arr = jnp.asarray(ks, dtype)
@@ -801,23 +897,32 @@ def run_cohort_grid(cohort, ks: Sequence[float] = PAPER_SCALE_RATIOS,
         if C > 1:
             k_l2 = jnp.repeat(k_l2, C, axis=1)
             s_l2 = jnp.repeat(s_l2, C, axis=1)
-        if resolved == "chunked":
-            lanes = _run_cohort_chunks(
-                spw, k_l2, s_l2, m_nodes, ring,
-                max(1, int(chunk_lanes or CHUNK_LANES)), chaos_l,
-                step_impl)
-        else:                   # fused
-            lanes = _run_cohort_fused(spw, k_l2, s_l2, m_nodes, ring,
-                                      chaos_l, step_impl)
+        sharding = None
+        if resolved == "fused":
+            k_l2, s_l2, chaos_l, sharding = _place_cohort_lanes(
+                k_l2, s_l2, chaos_l)
+    L = K * S * C
+    if resolved == "chunked":
+        lanes, segs, dispatches = _run_cohort_chunks(
+            spw, k_l2, s_l2, m_nodes, ring,
+            max(1, int(chunk_lanes or CHUNK_LANES)), chaos_l, step_impl)
+    else:                   # fused
+        lanes, segs, dispatches = _run_cohort_fused(
+            spw, k_l2, s_l2, m_nodes, ring, L, sharding, chaos_l, step_impl)
+    with obs.span("repro.study.gather"):
+        lanes, segs = jax.tree.map(np.asarray, (lanes, segs))
+        for sp, real, loops in dispatches:
+            _tally(sp, spw.n_jobs, lanes.n_groups[real],
+                   [segs[i] for i in loops])
         shape = (W, K, S) if C == 1 else (W, K, S, C)
         grids = jax.tree.map(
-            lambda x: np.asarray(x).reshape(shape + x.shape[2:]), lanes)
+            lambda x: x[:, :L].reshape(shape + x.shape[2:]), lanes)
         out = {name: jax.tree.map(lambda x, w=w: x[w], grids)
                for w, name in enumerate(cohort.names)}
         for name, m in out.items():
             _enforce_budget(m, on_budget_exhausted,
                             f"run_cohort_grid[{name}]", ks, s_props)
-        return out
+    return out
 
 
 def run_packet_grid(wl: Workload,

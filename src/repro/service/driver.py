@@ -45,12 +45,12 @@ add their output keys) when configured.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import precision
 from repro.core.des import ChaosConfig, pack_workload, resolve_ring
 from repro.core.sweep import (PAPER_SCALE_RATIOS, chaos_axis_len,
@@ -61,7 +61,8 @@ from repro.service.controller import (FaultAwareController,
 from repro.service.monitor import (FaultRegimeEstimator, RollingMonitor,
                                    window_signals)
 from repro.workload.lublin import Workload
-from repro.workload.windows import WindowSpec, iter_windows, n_dropped
+from repro.workload.windows import (WindowSpec, n_dropped, slice_window,
+                                    window_bounds)
 
 _ON_BUDGET_POLICIES = ("raise", "warn", "ignore", "degrade")
 _ORACLE_MODES = ("auto", "seq", "chunked", "fused")
@@ -347,169 +348,184 @@ def run_service(wl: Workload,
     aw_best_all = []
     consec_degraded = 0
 
-    for t, (lo, hi, win) in enumerate(iter_windows(wl, spec)):
-        dropped = (faults is not None and t in faults.drop_telemetry
-                   and monitor.has_state)
-        nan_tel = faults is not None and t in faults.nan_telemetry
-        forced = faults is not None and t in faults.exhaust_budget
+    for t, (lo, hi) in enumerate(window_bounds(len(wl.submit), spec)):
+        with obs.span("repro.service.tick", tick=t):
+            dropped = (faults is not None and t in faults.drop_telemetry
+                       and monitor.has_state)
+            nan_tel = faults is not None and t in faults.nan_telemetry
+            forced = faults is not None and t in faults.exhaust_budget
 
-        sig = window_signals(win, config.s_prop)
-        smooth = monitor.observe(_nan_signals(sig) if dropped else sig)
-        # dropped telemetry: the raw window never arrived — steer the
-        # oracle by the last smoothed init time instead
-        s_init = smooth["ewm_init_time"] if dropped else sig.init_time
+            with obs.span("repro.service.signals"):
+                win = slice_window(wl, lo, hi, rebase=spec.rebase)
+                sig = window_signals(win, config.s_prop)
+                smooth = monitor.observe(_nan_signals(sig) if dropped
+                                         else sig)
+            # dropped telemetry: the raw window never arrived — steer the
+            # oracle by the last smoothed init time instead
+            s_init = smooth["ewm_init_time"] if dropped else sig.init_time
 
-        with precision.dtype_scope(dtype):
-            pw = pack_workload(win, dtype)
-            ring = resolve_ring(m_nodes, pw.n_jobs)
-        t0 = time.perf_counter()
-        m = run_window_oracle(pw, config.ks, s_init, m_nodes,
-                              ring=ring, mode=config.mode,
-                              chaos=config.chaos,
-                              on_budget_exhausted="ignore")
-        oracle_ms = (time.perf_counter() - t0) * 1e3
-        exhausted = bool(np.any(np.asarray(m.budget_exhausted))) or forced
-        tick_label = (f"run_service tick {t} (window jobs "
-                      f"[{int(lo)}, {int(hi)}))")
+            with obs.span("repro.service.pack"), \
+                    precision.dtype_scope(dtype):
+                pw = pack_workload(win, dtype)
+                ring = resolve_ring(m_nodes, pw.n_jobs)
+            with obs.span("repro.service.oracle") as oracle:
+                m = run_window_oracle(pw, config.ks, s_init, m_nodes,
+                                      ring=ring, mode=config.mode,
+                                      chaos=config.chaos,
+                                      on_budget_exhausted="ignore")
+            oracle_ms = oracle.ms
+            exhausted = (bool(np.any(np.asarray(m.budget_exhausted)))
+                         or forced)
+            tick_label = (f"run_service tick {t} (window jobs "
+                          f"[{int(lo)}, {int(hi)}))")
 
-        if exhausted and policy != "ignore":
-            why = ("forced budget exhaustion (TickFaults)" if forced
-                   else "oracle lane(s) exhausted the event budget")
-            msg = (f"{tick_label}: {why} — schedules for this window are "
-                   f"untrustworthy; raise the event budget, or run with "
-                   f"on_budget_exhausted='degrade' to hold the last-good "
-                   f"k and continue")
-            if policy == "raise":
-                raise RuntimeError(msg)
-            if policy == "warn":
-                warnings.warn(msg, RuntimeWarning, stacklevel=2)
-            else:                   # degrade: hold last-good k, no scoring
-                consec_degraded += 1
-                if consec_degraded > config.max_consecutive_degraded:
-                    raise RuntimeError(
-                        f"{tick_label}: {consec_degraded} consecutive "
-                        f"degraded ticks exceed max_consecutive_degraded="
-                        f"{config.max_consecutive_degraded} — the oracle "
-                        f"never recovered; giving up")
-                tick = {"tick": t, "window": [int(lo), int(hi)],
-                        "signals": smooth, "oracle_ms": float(oracle_ms),
-                        "degraded": True, "controllers": {}}
+            if exhausted and policy != "ignore":
+                why = ("forced budget exhaustion (TickFaults)" if forced
+                       else "oracle lane(s) exhausted the event budget")
+                msg = (f"{tick_label}: {why} — schedules for this window "
+                       f"are untrustworthy; raise the event budget, or run "
+                       f"with on_budget_exhausted='degrade' to hold the "
+                       f"last-good k and continue")
+                if policy == "raise":
+                    raise RuntimeError(msg)
+                if policy == "warn":
+                    warnings.warn(msg, RuntimeWarning, stacklevel=2)
+                else:                   # degrade: hold last-good k, no scoring
+                    consec_degraded += 1
+                    if consec_degraded > config.max_consecutive_degraded:
+                        raise RuntimeError(
+                            f"{tick_label}: {consec_degraded} consecutive "
+                            f"degraded ticks exceed "
+                            f"max_consecutive_degraded="
+                            f"{config.max_consecutive_degraded} — the "
+                            f"oracle never recovered; giving up")
+                    tick = {"tick": t, "window": [int(lo), int(hi)],
+                            "signals": smooth, "oracle_ms": float(oracle_ms),
+                            "degraded": True, "controllers": {}}
+                    for ctl in controllers:
+                        name = ctl.name
+                        if live[name] is None:
+                            # degraded before bootstrap: start on the median
+                            # candidate — the most conservative plateau guess
+                            live[name] = float(ks[len(ks) // 2])
+                            reason = "degraded-bootstrap"
+                        else:
+                            reason = "degraded-hold"
+                        rec[name]["k"].append(float(live[name]))
+                        tick["controllers"][name] = {
+                            "realized_k": float(live[name]),
+                            "committed_k": float(live[name]),
+                            "moved": False, "reason": reason}
+                    ticks.append(tick)
+                    health.append({
+                        "tick": t, "window": [int(lo), int(hi)], "ok": False,
+                        "degraded": True, "cause": why,
+                        "consecutive_degraded": consec_degraded,
+                        "dropped_telemetry": bool(dropped),
+                        "held_k": {n: float(live[n]) for n in names}})
+                    continue
+            consec_degraded = 0
+
+            with obs.span("repro.service.score"):
+                aw2 = np.asarray(m.avg_wait, np.float64).reshape(K, -1)
+                uu2 = np.asarray(m.useful_util, np.float64).reshape(K, -1)
+                lost2 = np.asarray(m.lost_work, np.float64).reshape(K, -1)
+                fail2 = np.asarray(m.failures, np.float64).reshape(K, -1)
+                req2 = np.asarray(m.requeues, np.float64).reshape(K, -1)
+                # hindsight references live in the true environment's cell
+                aw = aw2[:, env]
+                uu = uu2[:, env]
+                i_best = int(np.argmin(aw))
+                best_uu = float(np.max(uu))
+                plat = plateau_threshold(ks, aw, rel_tol=config.rel_tol,
+                                         abs_tol=config.abs_tol)
+                i_plat = int(np.argmin(np.abs(ks - plat.threshold)))
+                aw_best_all.append(float(aw[i_best]))
+
+            tick = {"tick": t, "window": [int(lo), int(hi)],
+                    "signals": smooth, "oracle_ms": float(oracle_ms),
+                    "best_k": float(ks[i_best]),
+                    "best_wait": float(aw[i_best]),
+                    "plateau_k": float(plat.threshold),
+                    "plateau_wait": float(aw[i_plat]),
+                    "controllers": {}}
+
+            with obs.span("repro.service.decide"):
                 for ctl in controllers:
                     name = ctl.name
-                    if live[name] is None:
-                        # degraded before bootstrap: start on the median
-                        # candidate — the most conservative plateau guess
-                        live[name] = float(ks[len(ks) // 2])
-                        reason = "degraded-bootstrap"
+                    if with_chaos:
+                        est = estimators[name]
+                        weights = (est.weights(pred[name])
+                                   if pred[name] is not None
+                                   else np.full(C, 1.0 / C))
+                        if getattr(ctl, "fault_aware", False):
+                            dec = ctl.decide(ks, aw2, lost=lost2 / m_nodes,
+                                             weights=weights)
+                        else:
+                            dec = ctl.decide(ks, aw2 @ weights)
                     else:
-                        reason = "degraded-hold"
-                    rec[name]["k"].append(float(live[name]))
-                    tick["controllers"][name] = {
-                        "realized_k": float(live[name]),
-                        "committed_k": float(live[name]),
-                        "moved": False, "reason": reason}
-                ticks.append(tick)
+                        dec = ctl.decide(ks, aw)
+                    # actuation delay: tick t realizes the k held coming
+                    # INTO the tick; the new decision takes effect at t+1.
+                    # Bootstrap tick realizes the first decision (the
+                    # service starts with it).
+                    k_real = live[name] if live[name] is not None else dec.k
+                    live[name] = dec.k
+                    i_real = int(np.argmin(np.abs(ks - k_real)))
+                    r = rec[name]
+                    r["k"].append(float(k_real))
+                    r["realized_wait"].append(float(aw[i_real]))
+                    r["regret_wait"].append(float(aw[i_real] - aw[i_best]))
+                    r["regret_useful"].append(float(best_uu - uu[i_real]))
+                    r["wait_vs_plateau"].append(float(aw[i_real] - aw[i_plat]))
+                    if dec.moved and dec.reason != "bootstrap":
+                        r["switches"] += 1
+                    ctl_tick = {
+                        "realized_k": float(k_real),
+                        "committed_k": float(dec.k),
+                        "moved": bool(dec.moved), "reason": dec.reason,
+                        "hold_tol": float(dec.hold_tol)}
+                    if with_chaos:
+                        # realized fault telemetry (true environment's cell
+                        # at the realized k) closes the estimator's loop;
+                        # NaN injection exercises its carry-forward
+                        # hardening
+                        lost_real = float(lost2[i_real, env] / m_nodes)
+                        r["realized_lost"].append(lost_real)
+                        telemetry = ((float("nan"),) * 3 if nan_tel
+                                     else (float(fail2[i_real, env]),
+                                           float(req2[i_real, env]),
+                                           float(lost2[i_real, env])))
+                        est_out = estimators[name].observe(*telemetry)
+                        pred[name] = {"failures": fail2[i_real, :],
+                                      "requeues": req2[i_real, :],
+                                      "lost_work": lost2[i_real, :]}
+                        if getattr(ctl, "fault_aware", False):
+                            # close the λ loop: the realized wait/lost pair at
+                            # this tick's realized k re-prices lost work for
+                            # the NEXT tick's decide (no-op unless
+                            # adapt_lambda)
+                            ctl_tick["risk_lambda"] = float(ctl.live_lambda)
+                            obs_wait = (float("nan") if nan_tel
+                                        else float(aw[i_real]))
+                            obs_lost = float("nan") if nan_tel else lost_real
+                            ctl.observe_realized(obs_wait, obs_lost)
+                        ctl_tick["weights"] = [float(x) for x in weights]
+                        ctl_tick["realized_lost"] = lost_real
+                        ctl_tick["fault_ewm"] = {
+                            k: v for k, v in est_out.items()
+                            if k != "carried"}
+                        if est_out["carried"]:
+                            ctl_tick["carried_telemetry"] = est_out["carried"]
+                    tick["controllers"][name] = ctl_tick
+            ticks.append(tick)
+            if track_health:
                 health.append({
-                    "tick": t, "window": [int(lo), int(hi)], "ok": False,
-                    "degraded": True, "cause": why,
-                    "consecutive_degraded": consec_degraded,
+                    "tick": t, "window": [int(lo), int(hi)], "ok": True,
+                    "degraded": False, "consecutive_degraded": 0,
                     "dropped_telemetry": bool(dropped),
-                    "held_k": {n: float(live[n]) for n in names}})
-                continue
-        consec_degraded = 0
-
-        aw2 = np.asarray(m.avg_wait, np.float64).reshape(K, -1)
-        uu2 = np.asarray(m.useful_util, np.float64).reshape(K, -1)
-        lost2 = np.asarray(m.lost_work, np.float64).reshape(K, -1)
-        fail2 = np.asarray(m.failures, np.float64).reshape(K, -1)
-        req2 = np.asarray(m.requeues, np.float64).reshape(K, -1)
-        # hindsight references live in the true environment's cell
-        aw = aw2[:, env]
-        uu = uu2[:, env]
-        i_best = int(np.argmin(aw))
-        best_uu = float(np.max(uu))
-        plat = plateau_threshold(ks, aw, rel_tol=config.rel_tol,
-                                 abs_tol=config.abs_tol)
-        i_plat = int(np.argmin(np.abs(ks - plat.threshold)))
-        aw_best_all.append(float(aw[i_best]))
-
-        tick = {"tick": t, "window": [int(lo), int(hi)],
-                "signals": smooth, "oracle_ms": float(oracle_ms),
-                "best_k": float(ks[i_best]),
-                "best_wait": float(aw[i_best]),
-                "plateau_k": float(plat.threshold),
-                "plateau_wait": float(aw[i_plat]),
-                "controllers": {}}
-
-        for ctl in controllers:
-            name = ctl.name
-            if with_chaos:
-                est = estimators[name]
-                weights = (est.weights(pred[name])
-                           if pred[name] is not None
-                           else np.full(C, 1.0 / C))
-                if getattr(ctl, "fault_aware", False):
-                    dec = ctl.decide(ks, aw2, lost=lost2 / m_nodes,
-                                     weights=weights)
-                else:
-                    dec = ctl.decide(ks, aw2 @ weights)
-            else:
-                dec = ctl.decide(ks, aw)
-            # actuation delay: tick t realizes the k held coming INTO the
-            # tick; the new decision takes effect at t+1. Bootstrap tick
-            # realizes the first decision (the service starts with it).
-            k_real = live[name] if live[name] is not None else dec.k
-            live[name] = dec.k
-            i_real = int(np.argmin(np.abs(ks - k_real)))
-            r = rec[name]
-            r["k"].append(float(k_real))
-            r["realized_wait"].append(float(aw[i_real]))
-            r["regret_wait"].append(float(aw[i_real] - aw[i_best]))
-            r["regret_useful"].append(float(best_uu - uu[i_real]))
-            r["wait_vs_plateau"].append(float(aw[i_real] - aw[i_plat]))
-            if dec.moved and dec.reason != "bootstrap":
-                r["switches"] += 1
-            ctl_tick = {
-                "realized_k": float(k_real), "committed_k": float(dec.k),
-                "moved": bool(dec.moved), "reason": dec.reason,
-                "hold_tol": float(dec.hold_tol)}
-            if with_chaos:
-                # realized fault telemetry (true environment's cell at the
-                # realized k) closes the estimator's loop; NaN injection
-                # exercises its carry-forward hardening
-                lost_real = float(lost2[i_real, env] / m_nodes)
-                r["realized_lost"].append(lost_real)
-                obs = ((float("nan"),) * 3 if nan_tel
-                       else (float(fail2[i_real, env]),
-                             float(req2[i_real, env]),
-                             float(lost2[i_real, env])))
-                est_out = estimators[name].observe(*obs)
-                pred[name] = {"failures": fail2[i_real, :],
-                              "requeues": req2[i_real, :],
-                              "lost_work": lost2[i_real, :]}
-                if getattr(ctl, "fault_aware", False):
-                    # close the λ loop: the realized wait/lost pair at
-                    # this tick's realized k re-prices lost work for the
-                    # NEXT tick's decide (no-op unless adapt_lambda)
-                    ctl_tick["risk_lambda"] = float(ctl.live_lambda)
-                    obs_wait = float("nan") if nan_tel else float(aw[i_real])
-                    obs_lost = float("nan") if nan_tel else lost_real
-                    ctl.observe_realized(obs_wait, obs_lost)
-                ctl_tick["weights"] = [float(x) for x in weights]
-                ctl_tick["realized_lost"] = lost_real
-                ctl_tick["fault_ewm"] = {k: v for k, v in est_out.items()
-                                         if k != "carried"}
-                if est_out["carried"]:
-                    ctl_tick["carried_telemetry"] = est_out["carried"]
-            tick["controllers"][name] = ctl_tick
-        ticks.append(tick)
-        if track_health:
-            health.append({
-                "tick": t, "window": [int(lo), int(hi)], "ok": True,
-                "degraded": False, "consecutive_degraded": 0,
-                "dropped_telemetry": bool(dropped),
-                "nan_telemetry": bool(nan_tel),
-                "budget_warned": bool(exhausted and policy == "warn")})
+                    "nan_telemetry": bool(nan_tel),
+                    "budget_warned": bool(exhausted and policy == "warn")})
 
     if not ticks:
         raise ValueError(
