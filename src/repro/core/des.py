@@ -96,7 +96,7 @@ sweep lane axes (see repro.core.sweep):
     the cut rank is the first member the credit does not finish, the
     remnant is the rank span [cut, tail) with a done-work RESIDUAL
     carried for the partially credited head member. To keep the scan
-    step's scatter count flat, formation only STASHES the span identity
+    step's state writes flat, formation only STASHES the span identity
     in the group's ring slot — an int32 code ``1 + qlo*(N+1) + tail``
     in `grp_rem_cnt` plus the available credit in `grp_rem_w` — and the
     walk itself is DEFERRED to the finish event (`_resolve_remnant`),
@@ -107,7 +107,7 @@ sweep lane axes (see repro.core.sweep):
     done-work residual is not stored at all — a non-fragmented pool is
     one contiguous span, so the next formation recovers it as span work
     minus pool_w. Memory/budget cost: O(H + ring) extra scalars — three
-    [H] fields and three [ring] fields (scatter parity with the
+    [H] fields and three [ring] fields (write parity with the
     aggregate pool this replaces), never [N] member state, so the scan
     engine's vmap shape and `event_budget(N, R)` are unchanged (a
     requeue batch still funds at most one extra formation + finish). Count, oldest-submit and queue weight of a remnant are
@@ -894,6 +894,36 @@ def _check_step_impl(step_impl: str) -> str:
     return step_impl
 
 
+def _first(x, reduce):
+    """Index of the first `reduce`-extreme along axis 0, by iota compare:
+    `jnp.argmax` (reduce=jnp.max) or `jnp.argmin` (jnp.min). A 1-D row
+    (one vmapped lane) gives a scalar; an [n, T] block of lane columns
+    (the Pallas kernel's layout) gives a [1, T] row."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    hit = x == reduce(x, axis=0, keepdims=True)
+    return jnp.min(jnp.where(hit, rows, x.shape[0]), axis=0,
+                   keepdims=x.ndim > 1)
+
+
+def _rows(n, idx):
+    """Mask of row `idx` along an axis of size n: [n] for a scalar index,
+    [n, T] for a [1, T] row of indices."""
+    shape = (n,) + jnp.shape(idx)[1:]
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) == idx
+
+
+def _pick(x, sel):
+    """The element of `x` along axis 0 where `sel` holds (exactly one), as
+    a masked max over a -inf / int-min floor, so it comes back unchanged
+    (signed zeros and infinities included; a subnormal float flushes to
+    zero, as through any float op under XLA). Shapes as `_first`."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        low = jnp.asarray(-jnp.inf, x.dtype)
+    else:
+        low = jnp.asarray(jnp.iinfo(x.dtype).min, x.dtype)
+    return jnp.max(jnp.where(sel, x, low), axis=0, keepdims=x.ndim > 1)
+
+
 def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
                      st: _ScanState, *, r_cap: int = 0, chaos=None,
                      u_all=None):
@@ -907,6 +937,13 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
     reference (`ref.py`) that the lane-batched Pallas kernel body mirrors —
     one source of truth for the event arithmetic, so the engines cannot
     drift apart silently.
+
+    Every read and write along the small state axes (types H, ring slots)
+    is an iota-compare select (`_first`, `_pick`, masked `where` on
+    `_rows`), the kernel body's own form: exact, and under `vmap` free of
+    the batched gathers and scatters that cost per-index work on the TPU.
+    Only the job-axis tables of `pw` and the chaos stream `u_all` are
+    gathered.
 
     Args mirror `simulate_packet_scan`'s internals: `s_j`/`p_j`/`tmax_j`
     are the [H] per-type init/priority/wait-normalizer rows, `r_cap` the
@@ -946,12 +983,17 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
         oldest = jnp.minimum(oldest, st.pool_oldest)
     w = packet.queue_weights(sum_w, s_j, p_j, oldest, st.t, tmax_j,
                              nonempty)
-    j = jnp.argmax(w).astype(jnp.int32)
-    work = sum_w[j]
-    m_grp = packet.group_nodes(work, k, s_j[j], st.m_free)
-    dur = packet.group_duration(work, s_j[j], m_grp)
-    sslot = jnp.argmax(free_mask)
-    head_w = pw.tj_prefw[j, st.head[j]]
+    j = _first(w, jnp.max)
+    at_j = _rows(H, j)
+    work = _pick(sum_w, at_j)
+    s_grp = _pick(s_j, at_j)
+    tail_j = _pick(st.tail, at_j)
+    head_j = _pick(st.head, at_j)
+    m_grp = packet.group_nodes(work, k, s_grp, st.m_free)
+    dur = packet.group_duration(work, s_grp, m_grp)
+    at_s = _rows(free_mask.shape[0],
+                 _first(free_mask.astype(jnp.int32), jnp.max))
+    head_w = pw.tj_prefw[j, head_j]
     if chaos is None:
         t_gfin = st.t + dur
         useful_end = t_gfin
@@ -959,37 +1001,37 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
         L_cap = u_all.shape[0]
         gslot = jnp.minimum(st.n_groups, L_cap - 1)
         out = _chaos_outcome(chaos, u_all[gslot, 0], u_all[gslot, 1],
-                             st.requeues < R, s_j[j], work, m_grp, dur,
+                             st.requeues < R, s_grp, work, m_grp, dur,
                              dtype)
         t_gfin = st.t + out.dur
         useful_end = jnp.where(out.failed,
-                               st.t + s_j[j] + out.ckpt_done, t_gfin)
+                               st.t + s_grp + out.ckpt_done, t_gfin)
         requeued = do_sched & (out.failed | out.killed)
         # stash the requeue span + credit for the finish event — see
         # simulate_packet for the deferred-walk notes
         eps = jnp.asarray(CREDIT_EPS, dtype)
-        p_cnt, p_lo, p_frag = _pool_decode(st.pool_code[j], N)
+        p_cnt, p_lo, p_frag = _pool_decode(_pick(st.pool_code, at_j), N)
         has_pool = p_cnt > 0
-        qlo = jnp.where(has_pool, p_lo, st.head[j])
+        qlo = jnp.where(has_pool, p_lo, head_j)
         res0 = jnp.where(has_pool, jnp.maximum(
-            head_w - pw.tj_prefw[j, qlo] - st.pool_w[j], zero_f),
+            head_w - pw.tj_prefw[j, qlo] - _pick(st.pool_w, at_j), zero_f),
             zero_f)
         walk_ok = ~(has_pool & p_frag)
         avail = res0 + out.credit
-        span_code = 1 + qlo * (N + 1) + st.tail[j]
+        span_code = 1 + qlo * (N + 1) + tail_j
         rem_agg = work - out.credit
         a_has = requeued & (rem_agg > eps)
-        a_cnt = (st.tail[j] - st.head[j]) + p_cnt
+        a_cnt = (tail_j - head_j) + p_cnt
         code = jnp.where(requeued & walk_ok, span_code,
                          jnp.where(a_has, -a_cnt, zero_i))
         stash_w = jnp.where(
             requeued & walk_ok, avail,
             jnp.where(a_has, jnp.maximum(rem_agg, zero_f), zero_f))
-        stash_old = jnp.where(a_has & ~walk_ok, oldest[j], INF)
+        stash_old = jnp.where(a_has & ~walk_ok, _pick(oldest, at_j), INF)
     busy_inc = m_grp.astype(dtype) * _window_overlap(
         st.t, t_gfin, t_end_metric)
     useful_inc = m_grp.astype(dtype) * _window_overlap(
-        st.t + s_j[j], useful_end, t_end_metric)
+        st.t + s_grp, useful_end, t_end_metric)
     if chaos is not None:
         # same best-effort rounding contract as the while engine
         busy_inc, useful_inc = jax.lax.optimization_barrier(
@@ -998,8 +1040,8 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
     # event step (submission or completion), masked unless do_event
     t_sub = jnp.where(st.next_sub < N,
                       pw.submit[jnp.minimum(st.next_sub, N - 1)], INF)
-    eslot = jnp.argmin(st.grp_end)
-    t_efin = st.grp_end[eslot]
+    at_e = _rows(st.grp_end.shape[0], _first(st.grp_end, jnp.min))
+    t_efin = jnp.min(st.grp_end)        # the element at the first argmin
     take_sub = t_sub <= t_efin
     t_new = jnp.where(take_sub, t_sub, t_efin)
     qlen = jnp.sum(st.tail - st.head).astype(dtype)
@@ -1012,21 +1054,18 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
 
     do_submit = do_event & take_sub
     do_finish = do_event & ~take_sub
+    set_j = at_j & do_sched
+    set_s = at_s & do_sched
+    clr_e = at_e & do_finish
 
-    head = st.head.at[j].set(jnp.where(do_sched, st.tail[j], st.head[j]))
-    tail = st.tail.at[sub_j].add(jnp.where(do_submit, one_i, zero_i))
+    head = jnp.where(set_j, st.tail, st.head)
+    tail = st.tail + jnp.where(_rows(H, sub_j) & do_submit, one_i, zero_i)
     m_free = (st.m_free - jnp.where(do_sched, m_grp, zero_i)
-              + jnp.where(do_finish, st.grp_m[eslot], zero_i))
-    grp_end = st.grp_end.at[sslot].set(
-        jnp.where(do_sched, t_gfin, st.grp_end[sslot]))
-    grp_end = grp_end.at[eslot].set(
-        jnp.where(do_finish, INF, grp_end[eslot]))
-    grp_m = st.grp_m.at[sslot].set(
-        jnp.where(do_sched, m_grp, st.grp_m[sslot]))
-    grp_m = grp_m.at[eslot].set(
-        jnp.where(do_finish, zero_i, grp_m[eslot]))
+              + jnp.where(do_finish, _pick(st.grp_m, at_e), zero_i))
+    grp_end = jnp.where(clr_e, INF, jnp.where(set_s, t_gfin, st.grp_end))
+    grp_m = jnp.where(clr_e, zero_i, jnp.where(set_s, m_grp, st.grp_m))
 
-    y = (jnp.where(do_sched, j * (N + 1) + st.tail[j], key_pad),
+    y = (jnp.where(do_sched, j * (N + 1) + tail_j, key_pad),
          jnp.where(do_sched, st.t, zero_f),
          jnp.where(do_sched, m_grp, zero_i),
          jnp.where(do_sched, head_w, zero_f))
@@ -1037,52 +1076,39 @@ def packet_scan_step(pw: PackedWorkload, k, s_j, p_j, tmax_j,
         # formation clears the drained pool and stashes the requeue in
         # the ring; the finish event resolves the stash into its member
         # set (_resolve_remnant) and releases it back to the pool
-        j_f = st.grp_jtype[eslot]
+        j_f = _pick(st.grp_jtype, at_e)
+        at_f = _rows(H, j_f)
         cnt_r, rem_w_r, rem_old_r, rem_lo_r, rem_hi_r, walk_r = (
-            _resolve_remnant(pw, j_f, st.grp_rem_cnt[eslot],
-                             st.grp_rem_w[eslot],
-                             st.grp_rem_oldest[eslot], dtype))
-        old_cnt, old_lo, old_frag = _pool_decode(st.pool_code[j_f], N)
+            _resolve_remnant(pw, j_f, _pick(st.grp_rem_cnt, at_e),
+                             _pick(st.grp_rem_w, at_e),
+                             _pick(st.grp_rem_oldest, at_e), dtype))
+        old_cnt, old_lo, old_frag = _pool_decode(
+            _pick(st.pool_code, at_f), N)
         inc = do_finish & (cnt_r > 0)
         was_empty = old_cnt == 0
-        contig = rem_hi_r == st.head[j_f]
+        contig = rem_hi_r == _pick(st.head, at_f)
         frag = jnp.where(
             inc, old_frag | ~walk_r | ~was_empty | ~contig, old_frag)
         new_lo = jnp.where(was_empty, rem_lo_r,
                            jnp.minimum(old_lo, rem_lo_r))
         new_code = ((new_lo * 2 + frag.astype(jnp.int32))
                     * (N + 1) + old_cnt + cnt_r)
-        pool_w = st.pool_w.at[j].set(
-            jnp.where(do_sched, zero_f, st.pool_w[j]))
-        pool_w = pool_w.at[j_f].add(
-            jnp.where(do_finish, rem_w_r, zero_f))
-        pool_oldest = st.pool_oldest.at[j].set(
-            jnp.where(do_sched, INF, st.pool_oldest[j]))
-        pool_oldest = pool_oldest.at[j_f].min(
-            jnp.where(do_finish, rem_old_r, INF))
-        pool_code = st.pool_code.at[j].set(
-            jnp.where(do_sched, zero_i, st.pool_code[j]))
-        pool_code = pool_code.at[j_f].set(
-            jnp.where(inc, new_code, pool_code[j_f]))
-        grp_rem_w = st.grp_rem_w.at[sslot].set(
-            jnp.where(do_sched, stash_w, st.grp_rem_w[sslot]))
-        grp_rem_w = grp_rem_w.at[eslot].set(
-            jnp.where(do_finish, zero_f, grp_rem_w[eslot]))
-        grp_rem_cnt = st.grp_rem_cnt.at[sslot].set(
-            jnp.where(do_sched, code, st.grp_rem_cnt[sslot]))
-        grp_rem_cnt = grp_rem_cnt.at[eslot].set(
-            jnp.where(do_finish, zero_i, grp_rem_cnt[eslot]))
-        grp_rem_oldest = st.grp_rem_oldest.at[sslot].set(
-            jnp.where(do_sched, stash_old, st.grp_rem_oldest[sslot]))
-        grp_rem_oldest = grp_rem_oldest.at[eslot].set(
-            jnp.where(do_finish, INF, grp_rem_oldest[eslot]))
+        pool_w = jnp.where(set_j, zero_f, st.pool_w)
+        pool_oldest = jnp.where(set_j, INF, st.pool_oldest)
+        pool_code = jnp.where(set_j, zero_i, st.pool_code)
         chaos_upd = dict(
-            pool_w=pool_w, pool_oldest=pool_oldest,
-            pool_code=pool_code,
-            grp_jtype=st.grp_jtype.at[sslot].set(
-                jnp.where(do_sched, j, st.grp_jtype[sslot])),
-            grp_rem_w=grp_rem_w, grp_rem_cnt=grp_rem_cnt,
-            grp_rem_oldest=grp_rem_oldest,
+            pool_w=jnp.where(at_f, pool_w + jnp.where(
+                do_finish, rem_w_r, zero_f), pool_w),
+            pool_oldest=jnp.where(at_f, jnp.minimum(pool_oldest, jnp.where(
+                do_finish, rem_old_r, INF)), pool_oldest),
+            pool_code=jnp.where(at_f & inc, new_code, pool_code),
+            grp_jtype=jnp.where(set_s, j, st.grp_jtype),
+            grp_rem_w=jnp.where(clr_e, zero_f,
+                                jnp.where(set_s, stash_w, st.grp_rem_w)),
+            grp_rem_cnt=jnp.where(clr_e, zero_i,
+                                  jnp.where(set_s, code, st.grp_rem_cnt)),
+            grp_rem_oldest=jnp.where(clr_e, INF, jnp.where(
+                set_s, stash_old, st.grp_rem_oldest)),
             lost_work=st.lost_work + jnp.where(do_sched, out.lost,
                                                zero_f),
             failures=st.failures + jnp.where(do_sched & out.failed,

@@ -13,7 +13,8 @@ round-tripping each small intermediate through HBM.
 Mosaic form: every per-lane value is a [1, T] row and every access to
 the small state axes (types H, ring slots) is an iota-compare select —
 `_first` for argmax/argmin, `_pick` for a row read, masked `where` for a
-row write — which is exact and lowers on the TPU. Lookups along the job
+row write — which is exact and lowers on the TPU. The helpers live in
+`repro.core.des`, whose XLA step uses the same form. Lookups along the job
 axis (the per-type prefix tables at head/tail, the next submission, the
 chaos draws of the forming group and the finish event's credit walk)
 are gathered by `ops.step_gathers` in the enclosing XLA program before
@@ -37,7 +38,8 @@ import jax.numpy as jnp
 
 from repro.core import packet
 from repro.core.des import (CREDIT_EPS, INF, ChaosConfig, _ScanState,
-                            _chaos_outcome, _window_overlap)
+                            _chaos_outcome, _first, _pick, _rows,
+                            _window_overlap)
 
 #: number of _ScanState fields carried as [*, T] columns
 N_STATE_COLS = len(_ScanState._fields)
@@ -66,28 +68,6 @@ class ChaosGathers(NamedTuple):
     rem_lo: jnp.ndarray
     rem_hi: jnp.ndarray
     rem_walk: jnp.ndarray   # int32 0/1
-
-
-def _first(x, reduce):
-    """Row index (as [1, T]) of the first `reduce`-extreme of each column:
-    `jnp.argmax` (reduce=jnp.max) or `jnp.argmin` (jnp.min) over axis 0."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    hit = x == reduce(x, axis=0, keepdims=True)
-    return jnp.min(jnp.where(hit, rows, x.shape[0]), axis=0, keepdims=True)
-
-
-def _rows(n, idx):
-    """[n, T] mask of row `idx` ([1, T]) in each column."""
-    return jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[1]), 0) == idx
-
-
-def _pick(x, sel):
-    """The element of each column of `x` where `sel` holds (exactly one)."""
-    if jnp.issubdtype(x.dtype, jnp.floating):
-        low = jnp.asarray(-jnp.inf, x.dtype)
-    else:
-        low = jnp.asarray(jnp.iinfo(x.dtype).min, x.dtype)
-    return jnp.max(jnp.where(sel, x, low), axis=0, keepdims=True)
 
 
 def _any(x):
