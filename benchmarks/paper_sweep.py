@@ -52,8 +52,8 @@ import numpy as np
 
 from repro.compile_cache import enable_compile_cache
 from repro.core import (PAPER_INIT_PROPS, PAPER_SCALE_RATIOS, ChaosConfig,
-                        chaos_axis_len, group_workloads, run_baselines,
-                        run_cohort_grid, sweep_plan)
+                        chaos_axis_len, group_workloads,
+                        run_cohort_baselines, run_cohort_grid, sweep_plan)
 from repro.workload.lublin import paper_workloads
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
@@ -240,15 +240,18 @@ def run_full_grid(n_jobs: int | None = None, seed: int = 0,
     if chaos is not None:
         out["figure_scale_ratio_vs_faults"] = chaos_figure_data(out)
     if chaos is None:
-        for name, wl in flows.items():
-            wl_dtype, _ = decisions[name]
+        # the rigid baselines, one program per scheduler and cohort
+        for cohort in cohorts:
             t0 = time.time()
-            bl = jax.block_until_ready(run_baselines(wl, dtype=wl_dtype))
-            out["timing"][name]["baseline_seconds"] = time.time() - t0
-            out["baselines"][name] = {
-                alg: {f: np.asarray(getattr(m, f)).tolist()
-                      for f in BASELINE_FIELDS}
-                for alg, m in bl.items()}
+            runs = run_cohort_baselines(cohort)
+            dt = time.time() - t0
+            for name in cohort.names:
+                out["timing"][name]["baseline_seconds"] = \
+                    dt / cohort.n_workloads
+                out["baselines"][name] = {
+                    alg: {f: np.asarray(getattr(run.metrics, f)).tolist()
+                          for f in BASELINE_FIELDS}
+                    for alg, run in runs[name].items()}
     return out
 
 

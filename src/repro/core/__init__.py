@@ -12,13 +12,16 @@ from repro.core.des import (STEP_IMPLS, ChaosConfig, DesResult,
                             simulate_packet_reference, simulate_packet_scan,
                             simulate_packet_scan_lanes)
 from repro.core.metrics import Metrics, efficiency_metrics
-from repro.core.schedulers import simulate_backfill, simulate_fcfs
+from repro.core.schedulers import (RIGID_SCHEDULERS, RigidCounts,
+                                   simulate_backfill, simulate_fcfs,
+                                   simulate_rigid)
 from repro.core.sweep import (CHAOS_AXIS_FIELDS, PAPER_INIT_PROPS,
-                              PAPER_SCALE_RATIOS,
+                              PAPER_SCALE_RATIOS, BaselineRun,
                               PlateauResult, chaos_axis_len, chaos_lane_grid,
                               cohort_lane_sharding, lane_padding,
                               lane_sharding, plateau_threshold, resolve_mode,
-                              run_baselines, run_cohort_grid,
+                              run_baselines, run_cohort_baselines,
+                              run_cohort_grid,
                               run_packet_grid, run_window_oracle, sweep_plan)
 
 __all__ = [
@@ -29,11 +32,13 @@ __all__ = [
     "resolve_max_requeues", "resolve_ring", "simulate_packet",
     "simulate_packet_host", "simulate_packet_reference",
     "simulate_packet_scan", "simulate_packet_scan_lanes", "Metrics",
-    "efficiency_metrics", "simulate_backfill", "simulate_fcfs",
+    "efficiency_metrics", "RIGID_SCHEDULERS", "RigidCounts",
+    "simulate_backfill", "simulate_fcfs", "simulate_rigid",
     "CHAOS_AXIS_FIELDS", "PAPER_INIT_PROPS", "PAPER_SCALE_RATIOS",
-    "PlateauResult",
+    "BaselineRun", "PlateauResult",
     "chaos_axis_len", "chaos_lane_grid", "cohort_lane_sharding",
     "lane_padding", "lane_sharding", "plateau_threshold", "resolve_mode",
-    "run_baselines", "run_cohort_grid", "run_packet_grid",
+    "run_baselines", "run_cohort_baselines", "run_cohort_grid",
+    "run_packet_grid",
     "run_window_oracle", "sweep_plan",
 ]

@@ -43,6 +43,11 @@ stacks same-static workloads along a leading axis and the cohort kernel
 vmaps over (pw, k, s) with ``in_axes=(0, 0, 0, None, None)`` — nested over
 the per-lane vmap — so no workload table is ever replicated per lane.
 
+The rigid baselines (`repro.core.schedulers`: FCFS and EASY backfill) run
+on the same workload axis: `run_cohort_baselines` runs each scheduler as
+one program over a cohort's stacked flows x init proportions, and
+`run_baselines` is its one-member call.
+
 Why the scan engine: a vmapped `while_loop` (the PR-1 fused engine) carries
 the [lanes, N] group log through every lockstep iteration and scatters into
 it per event, which lost ~16x to sequential dispatch on one CPU device.
@@ -83,6 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.core import precision
+from repro.core.cohort import group_workloads
 from repro.core.des import (SCAN_SEG, STEP_IMPLS, ChaosConfig,
                             PackedWorkload, _check_step_impl,
                             chaos_is_inert, event_budget,
@@ -90,7 +96,7 @@ from repro.core.des import (SCAN_SEG, STEP_IMPLS, ChaosConfig,
                             resolve_ring, simulate_packet,
                             simulate_packet_scan, simulate_packet_scan_lanes)
 from repro.core.metrics import Metrics, efficiency_metrics
-from repro.core.schedulers import simulate_backfill, simulate_fcfs
+from repro.core.schedulers import RIGID_SCHEDULERS, simulate_rigid
 from repro.kernels.packet_step import ops as _step_ops
 from repro.workload.lublin import Workload
 
@@ -209,21 +215,6 @@ def _packet_s_row(pw, k, s_vals, m_nodes, ring):
     """One scale-ratio row batched over the init-proportion axis."""
     return jax.vmap(_one_experiment_scan, in_axes=(None, None, 0, None, None))(
         pw, k, s_vals, m_nodes, ring)
-
-
-@partial(jax.jit, static_argnames=("m_nodes", "ring"))
-def _baseline_lanes(pw, s_vals, m_nodes, ring):
-    """Both rigid baselines batched over the init-proportion axis."""
-    def fcfs_one(s):
-        res = simulate_fcfs(pw, s, m_nodes, ring=ring)
-        return efficiency_metrics(pw.submit, res, m_nodes, pw.t_last_submit)
-
-    def bf_one(s):
-        res = simulate_backfill(pw, s, m_nodes, ring=ring)
-        return efficiency_metrics(pw.submit, res, m_nodes, pw.t_last_submit)
-
-    return {"fcfs": jax.vmap(fcfs_one)(s_vals),
-            "backfill": jax.vmap(bf_one)(s_vals)}
 
 
 #: the ChaosConfig fields that may carry a chaos lane axis
@@ -1135,22 +1126,97 @@ def run_window_oracle(pw: PackedWorkload,
         return out
 
 
+# --------------------------------------------------------------------------
+# Rigid baselines (repro.core.schedulers) over a cohort.
+# --------------------------------------------------------------------------
+
+class BaselineRun(NamedTuple):
+    """One rigid scheduler's runs of one flow, one per init proportion."""
+    metrics: Metrics          # leaves [S]
+    start_t: np.ndarray       # [S, N] each job's start
+
+
+@partial(jax.jit, static_argnames=("scheduler", "m_nodes", "ring",
+                                   "backfill_depth"))
+def _rigid_cohort_lanes(spw, s_l2, scheduler, m_nodes, ring, backfill_depth):
+    """[W]-stacked workloads x [W, S] init times, one rigid scheduler: one
+    program of W * S lanes (the nesting of `_packet_cohort_lanes`).
+
+    Returns ``(Metrics, start_t, RigidCounts)``, leaves [W, S] and start
+    times [W, S, N]."""
+    def lane(pw, s):
+        res, counts = simulate_rigid(pw, s, m_nodes, scheduler,
+                                     backfill_depth=backfill_depth, ring=ring)
+        return (efficiency_metrics(pw.submit, res, m_nodes,
+                                   pw.t_last_submit), res.start_t, counts)
+
+    return jax.vmap(jax.vmap(lane, in_axes=(None, 0)))(spw, s_l2)
+
+
+def _tally_rigid(sp, n_jobs: int, counts, backfill_depth: int) -> None:
+    """Count a rigid dispatch on its span, from its counts on the host.
+
+    ``lane_events``: 2N per lane (each job is submitted and completes
+    once); ``lane_steps_run``: lanes x the outer trips of its longest lane
+    (a vmapped lane trips, masked, until the last one drains);
+    ``backfill_slots``: candidate trips run (`backfill_depth` per pass);
+    ``backfill_candidates``: the trips that held a waiting job."""
+    lanes = int(counts.trips.size)
+    sp.count("lane_events", 2 * n_jobs * lanes)
+    sp.count("lane_steps_run", lanes * int(counts.trips.max()))
+    sp.count("backfill_slots", backfill_depth * int(counts.passes.sum()))
+    sp.count("backfill_candidates", int(counts.candidates.sum()))
+
+
+def run_cohort_baselines(cohort, s_props: Sequence[float] = PAPER_INIT_PROPS,
+                         schedulers: Sequence[str] = RIGID_SCHEDULERS,
+                         backfill_depth: int = 64,
+                         on_budget_exhausted: str = "raise") -> dict:
+    """Rigid-scheduler runs of every member of a `WorkloadCohort`.
+
+    Returns ``{name: {scheduler: BaselineRun}}``: per init proportion the
+    `Metrics` (with `ok` and `budget_exhausted`) and each job's start time.
+    Each scheduler runs as one program over the cohort's [W] stacked flows
+    x S init proportions (converted per member, as in `run_cohort_grid`);
+    a lane's results do not depend on what shares its dispatch, so each
+    equals the single-flow `simulate_fcfs` / `simulate_backfill` run.
+    """
+    W, S = cohort.n_workloads, len(s_props)
+    with obs.span("repro.baselines", workloads=W, s_props=S,
+                  schedulers=",".join(schedulers)), \
+            precision.dtype_scope(cohort.dtype):
+        spw = cohort.pack()
+        s_l2 = jnp.asarray([[wl.init_time_for_proportion(p) for p in s_props]
+                            for wl in cohort.workloads], cohort.dtype)
+        dispatched = []
+        for sched in schedulers:
+            with obs.span("repro.sweep.dispatch", scheduler=sched,
+                          lanes=W * S, width=W * S) as sp:
+                out = _rigid_cohort_lanes(spw, s_l2, sched, cohort.m_nodes,
+                                          cohort.ring, backfill_depth)
+                obs.stamp_when_ready("repro.sweep.device", out)
+            dispatched.append((sched, sp, out))
+        runs = {name: {} for name in cohort.names}
+        for sched, sp, out in dispatched:
+            metrics, start_t, counts = jax.tree.map(np.asarray, out)
+            _tally_rigid(sp, spw.n_jobs, counts, backfill_depth)
+            for w, name in enumerate(cohort.names):
+                m = jax.tree.map(lambda x, w=w: x[w], metrics)
+                _enforce_budget(m, on_budget_exhausted,
+                                f"run_cohort_baselines[{name}, {sched}]")
+                runs[name][sched] = BaselineRun(m, start_t[w])
+    return runs
+
+
 def run_baselines(wl: Workload, s_props: Sequence[float] = PAPER_INIT_PROPS,
                   dtype=jnp.float32) -> dict[str, Metrics]:
-    """FCFS and EASY-backfill metrics per init proportion (rigid jobs).
-
-    Both baselines and all init proportions run as one batched program.
-    `dtype=jnp.float64` opts into the scoped x64 mode, as in
-    `run_packet_grid`.
-    """
-    with precision.dtype_scope(dtype):
-        pw = pack_workload(wl, dtype)
-        m_nodes = int(wl.params.nodes)
-        ring = resolve_ring(m_nodes, pw.n_jobs)
-        s_vals = jnp.asarray(
-            [wl.init_time_for_proportion(p) for p in s_props], dtype)
-        out = _baseline_lanes(pw, s_vals, m_nodes, ring)
-        return {name: jax.tree.map(np.asarray, m) for name, m in out.items()}
+    """FCFS and EASY-backfill metrics per init proportion (rigid jobs): the
+    one-member call of `run_cohort_baselines`. `dtype=jnp.float64` opts
+    into the scoped x64 mode, as in `run_packet_grid`."""
+    cohort, = group_workloads({"flow": wl}, dtype)
+    return {sched: run.metrics
+            for sched, run in run_cohort_baselines(cohort, s_props)
+            ["flow"].items()}
 
 
 class PlateauResult(NamedTuple):

@@ -102,6 +102,25 @@ class TestFcfsBackfill:
         assert float(b.start_t[2]) == pytest.approx(2.0)  # used extra node
         assert float(b.start_t[1]) <= float(f.start_t[1]) + 1e-5
 
+    def test_backfill_spends_extra_nodes(self):
+        # M=10: A (6 nodes) and X (4) at t=0; head H (8) at t=1; B and C
+        # (2 nodes, 500 s each) at t=2, 3. X ends at 10: H is reserved
+        # t=100 with 2 extra nodes. B takes them; C would delay H, so it
+        # waits for H to end. FCFS starts B only with H.
+        wl = _mk_workload([0.0, 0.0, 1.0, 2.0, 3.0],
+                          [100.0, 10.0, 100.0, 500.0, 500.0],
+                          [6, 4, 8, 2, 2], [0] * 5, 1, 10)
+        pw = pack_workload(wl)
+        b = simulate_backfill(pw, 0.0, 10)
+        f = simulate_fcfs(pw, 0.0, 10)
+        assert bool(b.ok) and bool(f.ok)
+        np.testing.assert_array_equal(np.asarray(b.start_t),
+                                      [0, 0, 100, 10, 200])
+        np.testing.assert_array_equal(np.asarray(f.start_t),
+                                      [0, 0, 100, 100, 200])
+        # the head starts at its reservation, not later
+        assert float(b.start_t[2]) <= 100.0
+
 
 class TestMetrics:
     def test_metrics_hand_computed(self):

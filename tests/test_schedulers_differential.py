@@ -61,7 +61,7 @@ class _RefSim:
         return bool(np.isinf(self.grp_end).any())
 
     def start_job(self, i):
-        t_fin = self.t + self.s + self.runtime[i]
+        t_fin = self.t + (self.s + self.runtime[i])
         slot = int(np.argmax(np.isinf(self.grp_end)))
         m = int(self.nodes[i])
         self.busy += m * _overlap(self.t, t_fin, self.t_end)
@@ -117,7 +117,9 @@ def ref_fcfs(submit, runtime, nodes, s_init, m_nodes, ring):
 
 
 def ref_backfill(submit, runtime, nodes, s_init, m_nodes, ring,
-                 backfill_depth=64):
+                 backfill_depth=64, reservations=None):
+    """EASY: `reservations` (a dict, if given) gets each head's first
+    shadow time, keyed by job index."""
     sim = _RefSim(submit, runtime, nodes, s_init, m_nodes, ring)
 
     def waiting_idx(sim):
@@ -137,24 +139,26 @@ def ref_backfill(submit, runtime, nodes, s_init, m_nodes, ring,
         head = w[0] if any_wait else 0
         n_head = int(sim.nodes[head]) if any_wait else 1
         order = np.argsort(sim.grp_end, kind="stable")
-        ends = sim.grp_end[order]
         frees = np.cumsum(sim.grp_m[order]) + sim.m_free
         enough = frees >= n_head
-        if enough.any():
-            shadow_i = int(np.argmax(enough))
-            shadow, free_at_shadow = ends[shadow_i], int(frees[shadow_i])
-        else:
-            shadow, free_at_shadow = np.inf, sim.m_free
-        extra = max(free_at_shadow - n_head, 0)
+        shadow = (sim.grp_end[order][int(np.argmax(enough))]
+                  if enough.any() else np.inf)
+        at_shadow = sim.m_free + int(sim.grp_m[sim.grp_end <= shadow].sum())
+        extra = max(at_shadow - n_head, 0)
+        if any_wait and reservations is not None:
+            reservations.setdefault(head, shadow)
 
-        # 3) up to backfill_depth candidates behind the head, in index order
+        # 3) up to backfill_depth candidates behind the head, in index
+        # order; one that runs past the shadow time uses extra nodes
         for i in [j for j in w if j != head][:backfill_depth]:
             fits_now = sim.nodes[i] <= sim.m_free
-            ends_before = sim.t + sim.s + sim.runtime[i] <= shadow
+            ends_before = sim.t + (sim.s + sim.runtime[i]) <= shadow
             within_extra = sim.nodes[i] <= extra
             if (fits_now and (ends_before or within_extra)
-                    and sim.slot_free() and any_wait):
+                    and sim.slot_free()):
                 sim.start_job(i)
+                if not ends_before:
+                    extra -= int(sim.nodes[i])
 
     return sim.run(sched, 4 * sim.N + 64)
 
@@ -222,6 +226,29 @@ class TestBackfillDifferential:
         with precision.dtype_scope(np.float64):
             res = simulate_backfill(pack_workload(wl, np.float64), s, m)
             assert_matches_reference(res, ref, rtol=1e-9, atol=1e-9)
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_head_never_starts_after_its_reservation(self, seed):
+        """EASY's guarantee: with exact runtimes, every job starts by the
+        shadow time it was first reserved as the blocked head."""
+        wl, s, m = random_quarter_workload(seed + 300)
+        ring = resolve_ring(m, wl.n_jobs)
+        reserved = {}
+        ref = ref_backfill(wl.submit, wl.runtime, wl.nodes, s, m, ring,
+                           reservations=reserved)
+        assert ref["ok"] and reserved
+        for job, shadow in reserved.items():
+            assert ref["start_t"][job] <= shadow, (job, shadow)
+        assert_matches_reference(simulate_backfill(pack_workload(wl), s, m),
+                                 ref)
+
+    def test_hand_case_spends_extra_nodes(self):
+        """Two backfill candidates that each fit the head's 2 extra nodes:
+        the first takes them, the second waits (tests/test_des.py)."""
+        submit, runtime, nodes = ([0, 0, 1, 2, 3], [100, 10, 100, 500, 500],
+                                  [6, 4, 8, 2, 2])
+        ref = ref_backfill(submit, runtime, nodes, 0.0, 10, 5)
+        np.testing.assert_array_equal(ref["start_t"], [0, 0, 100, 10, 200])
 
     def test_backfill_no_worse_than_fcfs_on_avg_start(self):
         """Sanity cross-check between the two references themselves."""
